@@ -39,6 +39,9 @@ def main() -> None:
     # _common reads the env at import; set it before the imports below
     os.environ["SOSD_BACKEND"] = args.backend
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     from benchmarks import (batching_effects, build_times, explain, key_size,
                             mixed_workload, moe_dispatch, pareto,
                             parallel_scaling, scaling, search_fn,

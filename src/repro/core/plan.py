@@ -6,13 +6,21 @@ position, then bounded last-mile search*.  This module makes that shape
 an explicit, inspectable value instead of a per-index closure:
 
     IndexBuild --lower()--> LookupPlan(bounds, data, last_mile)
+                                |.program(kind, backend)   -> (q, ..., ops) -> result
+                                |.operands(backend)        -> ops: keys + index state
                                 |.compile(backend)         -> q -> LB ranks
                                 |.compile_scan(m)          -> q -> (LB, window)
                                 |.compile_merged()         -> (q, delta) -> merged LB
-                                |.compile_merged_scan(m)   -> (q, delta) -> merged (LB, window)
                                 |.compile_instrumented()   -> (q, n_valid) -> (LB, health stats)
                                 |.compile_instrumented_merged()
                                                            -> (q, n_valid, delta) -> (LB, stats)
+
+A program takes the sorted keys and the index state as its LAST argument
+(the ``ops`` pytree), never as closure constants: the compiled program
+holds no key data (at SOSD's 200M keys that would be 1.6 GB baked into
+every program), and one program per op kind and batch bucket serves
+every generation of the same shape.  The ``compile*`` entry points bind
+a plan's own operands for direct calls.
 
 A plan is a `bounds` stage — the index's state pytree, a pure predict
 function ``(state, q) -> (lo, hi)`` with ``hi`` inclusive, and the
@@ -22,11 +30,13 @@ pluggable backend:
 
   ``"jnp"``     the vectorized `repro.core.search.SEARCH_FNS` searches,
                 bit-identical to the historical fused pipeline;
-  ``"pallas"``  the tile-binned `kernels/bounded_search` kernel consuming
-                the plan's bounds (any index), or — where an index
-                registers one — a fused whole-plan kernel executor
-                (`kernels/rmi_lookup` for RMI).  On CPU the kernels run
-                in interpret mode, so both backends execute everywhere.
+  ``"pallas"``  the `kernels/bounded_search` kernel consuming the plan's
+                bounds (any index), or — where an index registers one —
+                a fused whole-plan kernel executor (`kernels/rmi_lookup`
+                for RMI).  Off the TPU the kernels run in interpret mode,
+                so both backends execute everywhere; under a batch
+                sharded over several devices they run per device
+                (`jax.shard_map`).
 
 Both backends return the exact lower-bound rank, so they are
 bit-identical for every plan (pinned by tests/test_plan.py across the
@@ -41,33 +51,40 @@ the same seam (`benchmarks/_common.full_lookup_fn`).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import threading
 from typing import Any, Callable, Dict, Optional
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import base, search
 from repro.obs.health import HEALTH_DISP_BUCKETS, HEALTH_TRAFFIC_BUCKETS
 
-__all__ = ["BACKENDS", "BoundsStage", "LookupPlan", "health_stats_expr",
-           "lower", "pack_health_stats", "register_fused",
-           "FUSED_LOWERERS"]
+__all__ = ["BACKENDS", "BoundProgram", "BoundsStage", "FusedLowering",
+           "LookupPlan",
+           "health_stats_expr", "lower", "pack_health_stats",
+           "register_fused", "FUSED_LOWERERS"]
 
 #: The backend axis every lookup consumer can select on.
 BACKENDS = ("jnp", "pallas")
 
-#: index name -> (plan, interpret) -> fn(q) -> positions.  A fused
-#: executor replaces the whole predict+search pipeline with one kernel
-#: path; registered per index family, used by backend="pallas".
-FUSED_LOWERERS: Dict[str, Callable] = {}
+#: index name -> `FusedLowering`.  A fused executor replaces the whole
+#: predict+search pipeline with one kernel path; registered per index
+#: family, used by backend="pallas".
+FUSED_LOWERERS: Dict[str, "FusedLowering"] = {}
 
 
-def register_fused(name: str):
-    def deco(fn):
-        FUSED_LOWERERS[name] = fn
-        return fn
+def register_fused(name: str, lookup: Callable):
+    """Register ``prepare(plan) -> state`` (the decorated function) with
+    its pure ``lookup(state, data, q, interpret, planes)`` for one index
+    family."""
+    def deco(prepare):
+        FUSED_LOWERERS[name] = FusedLowering(prepare=prepare, lookup=lookup)
+        return prepare
 
     return deco
 
@@ -190,9 +207,108 @@ def pack_health_stats(stats) -> Any:
                             stats["traffic_hist"].astype(jnp.int64)])
 
 
+def _value_key(v):
+    """Hashable stand-in for one closure value of a predict function, or
+    None when the value has no structural identity (e.g. an array)."""
+    if v is None or isinstance(v, (bool, int, float, str, np.generic,
+                                   np.dtype)):
+        return (type(v), v)
+    if isinstance(v, (tuple, list)):
+        keys = tuple(_value_key(x) for x in v)
+        return None if any(k is None for k in keys) else (type(v), keys)
+    if callable(v) and hasattr(v, "__code__"):
+        return _fn_key(v)
+    return None
+
+
+def _fn_key(fn):
+    """Structural identity of a predict function: its code plus the values
+    it closes over.  Two generations built with the same hyperparameters
+    over key sets of the same size close over equal scalars, so they share
+    one compiled program; anything else falls back to object identity
+    (the program cache then holds the function, so the id stays unique)."""
+    code = getattr(fn, "__code__", None)
+    if code is None:
+        return ("id", id(fn))
+    cells = tuple(_value_key(c.cell_contents) for c in fn.__closure__ or ())
+    if any(k is None for k in cells):
+        return ("id", id(fn))
+    return (code, cells)
+
+
+@dataclasses.dataclass(frozen=True)
+class FusedLowering:
+    """A whole-plan kernel executor registered for one index family:
+    ``prepare(plan)`` builds its device state once per plan, and
+    ``lookup(state, data, q, interpret, planes)`` is the pure expression
+    the programs run."""
+
+    prepare: Callable
+    lookup: Callable
+
+
+def _width_class(max_err: int) -> int:
+    """The static window bound programs are built with: ``max_err``
+    rounded up to a power of two.  Any bound no tighter than the index's
+    own is still a valid bound, so results are unchanged (trip counts
+    and kernel windows are powers of two anyway), and generations whose
+    error bounds differ within a power of two share one program."""
+    max_err = int(max_err)
+    return 0 if max_err <= 0 else 1 << (max_err - 1).bit_length()
+
+
+@dataclasses.dataclass(frozen=True)
+class _Statics:
+    """Everything a plan's programs depend on besides their operands."""
+
+    predict: Callable
+    max_err: int
+    n: int
+    last_mile: str
+    point_only: bool
+    fused: Optional[FusedLowering]
+
+
+#: Process-wide program cache: (plan signature, kind, backend, ...) ->
+#: jitted program.  Bounded LRU; an evicted program is rebuilt on demand.
+_PROGRAMS: "collections.OrderedDict" = collections.OrderedDict()
+_PROGRAMS_MAX = 512
+_PROGRAMS_MU = threading.Lock()
+
+
+def _per_device(run, mesh):
+    """Run ``run(q, ops)`` on each device's slice of a batch sharded over
+    every axis of ``mesh``, with the operands replicated.  A Mosaic kernel
+    cannot be partitioned by the compiler, so sharded Pallas lookups go
+    through here; lanes are independent, so the result is unchanged."""
+    if mesh is None:
+        return run
+    batch = P(tuple(mesh.axis_names))
+    return jax.shard_map(run, mesh=mesh, in_specs=(batch, P()),
+                         out_specs=batch, check_vma=False)
+
+
+class BoundProgram:
+    """A program with its operands bound: ``bound(*args) ==
+    program(*args, operands)`` — the convenience face of a plan."""
+
+    def __init__(self, program, ops):
+        self.program = program
+        self.ops = ops
+
+    def __call__(self, *args):
+        return self.program(*args, self.ops)
+
+
 @dataclasses.dataclass(frozen=True, eq=False)
 class LookupPlan:
-    """One index lowered to predict -> bounded-search, backend-agnostic."""
+    """One index lowered to predict -> bounded-search, backend-agnostic.
+
+    Programs take the key array and the index state as ARGUMENTS — the
+    trailing ``ops`` pytree of `operands` — never as closure constants:
+    a compiled program holds no key data, and one program per (kind,
+    backend, batch bucket) serves every plan with the same `signature`.
+    """
 
     name: str
     bounds: BoundsStage
@@ -200,114 +316,142 @@ class LookupPlan:
     n: int
     last_mile: str = "binary"
     point_only: bool = False
-    fused: Optional[Callable] = None   # whole-plan kernel executor factory
+    fused: Optional[FusedLowering] = None   # whole-plan kernel executor
     meta: Dict[str, Any] = dataclasses.field(default_factory=dict)
-    # compiled-fn cache: (kind, backend, interpret, ...) -> jitted callable.
-    # Keyed per plan instance so repeated dispatch (registry generations,
-    # sharded batches) reuses one compiled program per shape bucket.
+    # per-plan cache: operands, bound callables, derived kernel state
     _cache: Dict[Any, Any] = dataclasses.field(
         default_factory=dict, repr=False)
 
-    # -- expression builders (pure, un-jitted — composable in transforms) --
-    def lb_expr(self, backend: str = "jnp", interpret: bool = False,
-                fused: Optional[bool] = None) -> Callable:
-        """``q -> int64 LB ranks`` as a pure expression.
+    # -- statics and operands ----------------------------------------------
+    @property
+    def statics(self) -> _Statics:
+        return _Statics(self.bounds.predict,
+                        _width_class(self.bounds.max_err), int(self.n),
+                        self.last_mile, self.point_only, self.fused)
 
-        ``fused=None`` uses the registered whole-plan kernel when the
-        backend is pallas and the index has one; ``fused=False`` forces
-        the generic bounds->`lower_bound_windows` path (parity tests
-        exercise both).
-        """
+    @property
+    def signature(self):
+        """Hashable identity of everything the programs close over."""
+        sig = self._cache.get("signature")
+        if sig is None:
+            sig = (self.name, int(self.n), self.statics.max_err,
+                   self.last_mile, self.point_only, self.fused,
+                   _fn_key(self.bounds.predict))
+            self._cache["signature"] = sig
+        return sig
+
+    def _use_fused(self, backend: str, fused: Optional[bool]) -> bool:
         if backend not in BACKENDS:
             raise ValueError(f"unknown backend {backend!r}; one of {BACKENDS}")
-        if self.point_only:
-            predict, state = self.bounds.predict, self.bounds.state
+        if backend != "pallas" or self.point_only:
+            return False
+        if fused is None:
+            return self.fused is not None
+        if fused and self.fused is None:
+            raise ValueError(f"plan {self.name!r} has no fused kernel executor")
+        return bool(fused)
 
-            def run_point(q):
-                found, pos = predict(state, q)
-                return jnp.where(found, pos, -1).astype(jnp.int64)
+    def kernel_serves(self, backend: str = "jnp",
+                      fused: Optional[bool] = None) -> bool:
+        """Whether the Pallas last-mile kernel (not its exact jnp
+        fallback for windows wider than one data tile) serves lookups;
+        False where no kernel runs (jnp backend, point-only plans)."""
+        from repro.kernels.bounded_search.ops import kernel_serves
 
-            return run_point
+        if backend != "pallas" or self.point_only:
+            return False
+        if self._use_fused(backend, fused):
+            width = self.operands(backend, True)["fused"].max_err
+        else:
+            width = self.bounds.max_err
+        return kernel_serves(int(width))
 
-        predict, state = self.bounds.predict, self.bounds.state
-        max_err, data = self.bounds.max_err, self.data
+    def operands(self, backend: str = "jnp",
+                 fused: Optional[bool] = None) -> Dict[str, Any]:
+        """The device pytree every program of this backend takes last:
+        the sorted keys, the index state and, for the Pallas backend, the
+        kernel's data planes and the fused executor's state.  Built once
+        per plan."""
+        fused = self._use_fused(backend, fused)
+        key = ("ops", backend, fused)
+        ops = self._cache.get(key)
+        if ops is not None:
+            return ops
+        ops = {"data": self.data, "state": self.bounds.state}
+        if fused:
+            ops["fused"] = self.fused.prepare(self)
+        if backend == "pallas" and not self.point_only:
+            from repro.kernels.bounded_search import ops as bs
 
-        if backend == "pallas":
-            if fused is None:
-                fused = self.fused is not None
-            if fused:
-                if self.fused is None:
-                    raise ValueError(
-                        f"plan {self.name!r} has no fused kernel executor")
-                inner = self.fused(self, interpret)
-                return lambda q: inner(q).astype(jnp.int64)
+            width = (ops["fused"].max_err if fused else self.bounds.max_err)
+            if bs.kernel_serves(int(width)):
+                ops["planes"] = jax.jit(bs.kernel_planes)(self.data)
+        self._cache[key] = ops
+        return ops
 
-            from repro.kernels.bounded_search.ops import lower_bound_windows
-
-            def run_pallas(q):
-                lo, _hi = predict(state, q)
-                # window precondition lo <= LB < lo + max_err holds by the
-                # bounds contract (LB <= hi <= lo + max_err - 1)
-                return lower_bound_windows(
-                    data, q, lo, max_width=max_err,
-                    interpret=interpret).astype(jnp.int64)
-
-            return run_pallas
-
-        fn = search.SEARCH_FNS[self.last_mile]
-
-        def run_jnp(q):
-            lo, hi = predict(state, q)
-            return fn(data, q, lo, hi, max_err).astype(jnp.int64)
-
-        return run_jnp
+    # -- expression builders (pure, over (q, ..., ops)) ---------------------
+    def _lb_run(self, backend, interpret, mesh):
+        return _per_device(_lb_expr(self.statics, backend,
+                                    self._use_fused(backend, None),
+                                    interpret), mesh)
 
     def merged_expr(self, backend: str = "jnp",
-                    interpret: bool = False) -> Callable:
+                    interpret: Optional[bool] = None,
+                    mesh=None) -> Callable:
         """Delta rank correction as a plan transform (DESIGN.md §10.2):
-        ``(q, delta_padded) -> LB_base(q) + LB_delta(q)``.  Exact because
-        base and delta are disjoint sorted sets; the padded delta's
-        UINT64_MAX sentinels can never be counted by a lower bound."""
-        run = self.lb_expr(backend, interpret)
+        ``(q, delta_padded, ops) -> LB_base(q) + LB_delta(q)``.  Exact
+        because base and delta are disjoint sorted sets; the padded
+        delta's UINT64_MAX sentinels can never be counted by a lower
+        bound."""
+        run = self._lb_run(backend, interpret, mesh)
 
-        def merged(q, delta_padded):
-            lb_base = run(q)
+        def merged(q, delta_padded, ops):
             lb_delta = jnp.searchsorted(delta_padded, q, side="left")
-            return lb_base + lb_delta.astype(jnp.int64)
+            return run(q, ops) + lb_delta.astype(jnp.int64)
 
         return merged
 
     def scan_expr(self, m: int, backend: str = "jnp",
-                  interpret: bool = False) -> Callable:
-        """Range-scan materialization: ``q -> (LB, window[B, m])`` — the
-        ``m`` records from ``LB(q)`` as one static-width windowed gather."""
+                  interpret: Optional[bool] = None, mesh=None) -> Callable:
+        """Range-scan materialization: ``(q, ops) -> (LB, window[B, m])``
+        — the ``m`` records from ``LB(q)`` as one static-width windowed
+        gather.  Where ``ops`` carries a ``spill`` (the head of the next
+        key range of a routed topology), the window is completed from it:
+        the first ``m`` of the sorted union, the same argument as the
+        delta merged scan."""
         if self.point_only:
             raise ValueError(f"{self.name!r} is point-only: no scans")
-        run = self.lb_expr(backend, interpret)
-        data = self.data
+        run = self._lb_run(backend, interpret, mesh)
 
-        def scan(q):
-            pos = run(q)
-            return pos, _window_gather(data, pos, m)
+        def scan(q, ops):
+            pos = run(q, ops)
+            window = _window_gather(ops["data"], pos, m)
+            if "spill" in ops:
+                spill = jnp.broadcast_to(ops["spill"][None, :m],
+                                         (q.shape[0], m))
+                window = jnp.sort(jnp.concatenate([window, spill], axis=1),
+                                  axis=1)[:, :m]
+            return pos, window
 
         return scan
 
     def merged_scan_expr(self, m: int, backend: str = "jnp",
-                         interpret: bool = False) -> Callable:
+                         interpret: Optional[bool] = None,
+                         mesh=None) -> Callable:
         """Scan over the merged (base + delta) view: gather ``m`` from each
         side and keep the first ``m`` of their sorted union — exact because
         the merged array's next ``m`` records are contained in the union of
         the two windows, and both pad with the UINT64_MAX sentinel."""
         if self.point_only:
             raise ValueError(f"{self.name!r} is point-only: no scans")
-        run = self.lb_expr(backend, interpret)
-        data = self.data
+        run = self._lb_run(backend, interpret, mesh)
 
-        def scan(q, delta_padded):
-            pos_b = run(q)
+        def scan(q, delta_padded, ops):
+            pos_b = run(q, ops)
             pos_d = jnp.searchsorted(
                 delta_padded, q, side="left").astype(jnp.int64)
-            wb = _window_gather(data, pos_b, m).astype(delta_padded.dtype)
+            wb = _window_gather(ops["data"], pos_b, m).astype(
+                delta_padded.dtype)
             wd = _window_gather(delta_padded, pos_d, m)
             window = jnp.sort(
                 jnp.concatenate([wb, wd], axis=-1), axis=-1)[:, :m]
@@ -315,35 +459,36 @@ class LookupPlan:
 
         return scan
 
-    def _instr_base_expr(self, backend: str, interpret: bool) -> Callable:
-        """``(q, n_valid) -> (LB, lo, hi)`` sharing ONE predict between
-        the search and the stats on the generic jnp path (the fused /
-        pallas paths keep their own lookup and pay a second jnp predict
-        for the stats — still backend-invariant by construction)."""
-        predict, state = self.bounds.predict, self.bounds.state
+    def _instr_base_expr(self, backend: str, interpret: Optional[bool],
+                         mesh=None) -> Callable:
+        """``(q, ops) -> (LB, lo, hi)`` sharing ONE predict between the
+        search and the stats on the generic jnp path (the fused / pallas
+        paths keep their own lookup and pay a second jnp predict for the
+        stats — still backend-invariant by construction)."""
+        predict = self.bounds.predict
         if backend == "jnp":
             fn = search.SEARCH_FNS[self.last_mile]
-            data, max_err = self.data, self.bounds.max_err
+            max_err = self.statics.max_err
 
-            def base_jnp(q):
-                lo, hi = predict(state, q)
-                pos = fn(data, q, lo, hi, max_err).astype(jnp.int64)
+            def base_jnp(q, ops):
+                lo, hi = predict(ops["state"], q)
+                pos = fn(ops["data"], q, lo, hi, max_err).astype(jnp.int64)
                 return pos, lo, hi
 
             return base_jnp
 
-        run = self.lb_expr(backend, interpret)
+        run = self._lb_run(backend, interpret, mesh)
 
-        def base_other(q):
-            pos = run(q)
-            lo, hi = predict(state, q)
-            return pos, lo, hi
+        def base_other(q, ops):
+            lo, hi = predict(ops["state"], q)
+            return run(q, ops), lo, hi
 
         return base_other
 
     def instrumented_expr(self, backend: str = "jnp",
-                          interpret: bool = False) -> Callable:
-        """``(q, n_valid) -> (LB, packed stats)``: the lookup plus the
+                          interpret: Optional[bool] = None,
+                          mesh=None) -> Callable:
+        """``(q, n_valid, ops) -> (LB, packed stats)``: the lookup plus the
         `health_stats_expr` reduction flattened by `pack_health_stats`.
 
         The positions come from the SAME ops as the uninstrumented
@@ -353,40 +498,41 @@ class LookupPlan:
         ``n_valid`` is a dynamic int32 scalar so one compiled program
         serves every occupancy of a padded batch bucket.
         """
-        n, max_err = self.n, self.bounds.max_err
+        n, max_err = self.n, self.statics.max_err
         if self.point_only:
-            run = self.lb_expr(backend, interpret)
+            run = self._lb_run(backend, interpret, mesh)
 
-            def run_point_instr(q, n_valid):
-                pos = run(q)
+            def run_point_instr(q, n_valid, ops):
+                pos = run(q, ops)
                 stats = health_stats_expr(
                     pos, None, None, n, max_err, n_valid, point_only=True)
                 return pos, pack_health_stats(stats)
 
             return run_point_instr
 
-        base = self._instr_base_expr(backend, interpret)
+        base = self._instr_base_expr(backend, interpret, mesh)
 
-        def run_instr(q, n_valid):
-            pos, lo, hi = base(q)
+        def run_instr(q, n_valid, ops):
+            pos, lo, hi = base(q, ops)
             stats = health_stats_expr(pos, lo, hi, n, max_err, n_valid)
             return pos, pack_health_stats(stats)
 
         return run_instr
 
     def instrumented_merged_expr(self, backend: str = "jnp",
-                                 interpret: bool = False) -> Callable:
-        """``(q, n_valid, delta_padded) -> (merged LB, packed stats)``.
-        Stats describe the BASE plan (its model is what health tracks);
-        the payload is exactly `merged_expr`'s rank."""
+                                 interpret: Optional[bool] = None,
+                                 mesh=None) -> Callable:
+        """``(q, n_valid, delta_padded, ops) -> (merged LB, packed
+        stats)``.  Stats describe the BASE plan (its model is what health
+        tracks); the payload is exactly `merged_expr`'s rank."""
         if self.point_only:
             raise ValueError(
                 f"{self.name!r} is point-only: no merged lookups")
-        base = self._instr_base_expr(backend, interpret)
-        n, max_err = self.n, self.bounds.max_err
+        base = self._instr_base_expr(backend, interpret, mesh)
+        n, max_err = self.n, self.statics.max_err
 
-        def merged_instr(q, n_valid, delta_padded):
-            lb_base, lo, hi = base(q)
+        def merged_instr(q, n_valid, delta_padded, ops):
+            lb_base, lo, hi = base(q, ops)
             lb_delta = jnp.searchsorted(delta_padded, q, side="left")
             stats = health_stats_expr(lb_base, lo, hi, n, max_err, n_valid)
             return (lb_base + lb_delta.astype(jnp.int64),
@@ -394,64 +540,106 @@ class LookupPlan:
 
         return merged_instr
 
-    # -- compiled entry points (cached per plan) ---------------------------
-    def _compiled(self, key, make_expr, donate_argnums=()) -> Callable:
+    # -- programs (shared across plans) and bound entry points -------------
+    def program(self, kind: str, backend: str = "jnp",
+                interpret: Optional[bool] = None,
+                fused: Optional[bool] = None, m: Optional[int] = None,
+                donate: bool = False, mesh=None) -> Callable:
+        """The jitted program of one op kind; ``ops`` is its last argument:
+
+          ``"lb"``            ``(q, ops) -> LB``
+          ``"instr"``         ``(q, n_valid, ops) -> (LB, stats)``
+          ``"merged"``        ``(q, delta, ops) -> LB``
+          ``"instr_merged"``  ``(q, n_valid, delta, ops) -> (LB, stats)``
+          ``"scan"``          ``(q, ops) -> (LB, window[B, m])``
+          ``"merged_scan"``   ``(q, delta, ops) -> (LB, window[B, m])``
+
+        Cached process-wide by `signature`, so every generation of the
+        same shape reuses one program.  ``mesh`` is the mesh a batch is
+        sharded over: Pallas lookups then run per device (`jax.shard_map`).
+        ``donate`` donates the query buffer (safe on the dispatcher's
+        fresh placements).
+        """
+        # only the plain lookup can pick the generic path over a fused one
+        fused = self._use_fused(backend, fused if kind == "lb" else None)
+        if mesh is not None and backend != "pallas":
+            mesh = None            # the jnp path partitions automatically
+        key = (self.signature, kind, backend, interpret, fused,
+               None if m is None else int(m), bool(donate), mesh)
+        with _PROGRAMS_MU:
+            fn = _PROGRAMS.get(key)
+            if fn is not None:
+                _PROGRAMS.move_to_end(key)
+                return fn
+        fn = jax.jit(self._program_expr(kind, backend, interpret, fused, m,
+                                        mesh),
+                     donate_argnums=(0,) if donate else ())
+        with _PROGRAMS_MU:
+            fn = _PROGRAMS.setdefault(key, fn)
+            while len(_PROGRAMS) > _PROGRAMS_MAX:
+                _PROGRAMS.popitem(last=False)
+        return fn
+
+    def _program_expr(self, kind, backend, interpret, fused, m, mesh):
+        # expressions close over the plan's statics only (never `self`),
+        # so a cached program holds no key array alive
+        if kind == "lb":
+            return _per_device(_lb_expr(self.statics, backend, fused,
+                                        interpret), mesh)
+        if kind == "instr":
+            return self.instrumented_expr(backend, interpret, mesh)
+        if kind == "merged":
+            return self.merged_expr(backend, interpret, mesh)
+        if kind == "instr_merged":
+            return self.instrumented_merged_expr(backend, interpret, mesh)
+        if kind == "scan":
+            return self.scan_expr(int(m), backend, interpret, mesh)
+        if kind == "merged_scan":
+            return self.merged_scan_expr(int(m), backend, interpret, mesh)
+        raise ValueError(f"unknown program kind {kind!r}")
+
+    def _bound(self, kind, backend, interpret, fused=None, m=None,
+               donate=False) -> Callable:
+        fused = self._use_fused(backend, fused)
+        key = ("bound", kind, backend, interpret, fused, m, donate)
         fn = self._cache.get(key)
         if fn is None:
-            fn = jax.jit(make_expr(), donate_argnums=donate_argnums)
+            fn = BoundProgram(
+                self.program(kind, backend, interpret, fused, m, donate),
+                self.operands(backend, fused))
             self._cache[key] = fn
         return fn
 
-    def compile(self, backend: str = "jnp", interpret: bool = False,
+    def compile(self, backend: str = "jnp", interpret: Optional[bool] = None,
                 fused: Optional[bool] = None,
                 donate: bool = False) -> Callable:
-        """jit'd ``q -> int64 LB ranks`` (the canonical fused lookup).
-
-        ``donate=True`` donates the query buffer to XLA — safe when the
-        caller stages each batch into a fresh/reusable device placement
-        (the dispatcher does); a no-op with a warning on CPU."""
-        # normalize fused before keying the cache: the default (None) and
-        # its resolved value must alias to ONE compiled program
-        if backend != "pallas" or self.point_only:
-            fused = None
-        elif fused is None:
-            fused = self.fused is not None
-        return self._compiled(
-            ("lb", backend, interpret, fused, donate),
-            lambda: self.lb_expr(backend, interpret, fused),
-            donate_argnums=(0,) if donate else ())
+        """``q -> int64 LB ranks`` with this plan's operands bound (the
+        canonical fused lookup).  ``donate=True`` donates the query
+        buffer to XLA — safe when the caller stages each batch into a
+        fresh device placement (the dispatcher does)."""
+        return self._bound("lb", backend, interpret, fused, donate=donate)
 
     def compile_merged(self, backend: str = "jnp",
-                       interpret: bool = False) -> Callable:
-        return self._compiled(
-            ("merged", backend, interpret),
-            lambda: self.merged_expr(backend, interpret))
+                       interpret: Optional[bool] = None) -> Callable:
+        """``(q, delta) -> merged LB`` with this plan's operands bound."""
+        return self._bound("merged", backend, interpret)
 
     def compile_scan(self, m: int, backend: str = "jnp",
-                     interpret: bool = False) -> Callable:
-        return self._compiled(
-            ("scan", int(m), backend, interpret),
-            lambda: self.scan_expr(int(m), backend, interpret))
-
-    def compile_merged_scan(self, m: int, backend: str = "jnp",
-                            interpret: bool = False) -> Callable:
-        return self._compiled(
-            ("merged_scan", int(m), backend, interpret),
-            lambda: self.merged_scan_expr(int(m), backend, interpret))
+                     interpret: Optional[bool] = None) -> Callable:
+        """``q -> (LB, window)`` with this plan's operands bound."""
+        return self._bound("scan", backend, interpret, m=int(m))
 
     def compile_instrumented(self, backend: str = "jnp",
-                             interpret: bool = False,
+                             interpret: Optional[bool] = None,
                              donate: bool = False) -> Callable:
-        return self._compiled(
-            ("instr", backend, interpret, donate),
-            lambda: self.instrumented_expr(backend, interpret),
-            donate_argnums=(0,) if donate else ())
+        """``(q, n_valid) -> (LB, stats)`` with this plan's operands bound."""
+        return self._bound("instr", backend, interpret, donate=donate)
 
     def compile_instrumented_merged(self, backend: str = "jnp",
-                                    interpret: bool = False) -> Callable:
-        return self._compiled(
-            ("instr_merged", backend, interpret),
-            lambda: self.instrumented_merged_expr(backend, interpret))
+                                    interpret: Optional[bool] = None
+                                    ) -> Callable:
+        """``(q, n_valid, delta) -> (merged LB, stats)``, operands bound."""
+        return self._bound("instr_merged", backend, interpret)
 
     def build_displacement_quantile(self, q: float = 0.99,
                                     sample: int = 65536) -> float:
@@ -481,9 +669,58 @@ class LookupPlan:
         return val
 
     def scan(self, q, m: int, backend: str = "jnp",
-             interpret: bool = False):
+             interpret: Optional[bool] = None):
         """Convenience: materialize ``m`` records from ``LB(q)``."""
         return self.compile_scan(m, backend, interpret)(q)
+
+
+def _lb_expr(st: _Statics, backend: str, fused: bool,
+             interpret: Optional[bool]) -> Callable:
+    """``(q, ops) -> int64 LB ranks`` from a plan's statics alone.
+
+    ``fused`` selects the registered whole-plan kernel over the generic
+    bounds -> `lower_bound_windows` path (pallas only; parity tests run
+    both).  ``interpret=None`` runs Mosaic kernels on TPU and the Pallas
+    interpreter elsewhere."""
+    predict = st.predict
+    if st.point_only:
+        def run_point(q, ops):
+            found, pos = predict(ops["state"], q)
+            return jnp.where(found, pos, -1).astype(jnp.int64)
+
+        return run_point
+
+    if backend == "pallas":
+        if fused:
+            lookup = st.fused.lookup
+
+            def run_fused(q, ops):
+                return lookup(ops["fused"], ops["data"], q,
+                              interpret=interpret,
+                              planes=ops.get("planes")).astype(jnp.int64)
+
+            return run_fused
+
+        from repro.kernels.bounded_search.ops import lower_bound_windows
+
+        def run_pallas(q, ops):
+            lo, _hi = predict(ops["state"], q)
+            # window precondition lo <= LB < lo + max_err holds by the
+            # bounds contract (LB <= hi <= lo + max_err - 1)
+            return lower_bound_windows(
+                ops["data"], q, lo, max_width=st.max_err,
+                interpret=interpret,
+                planes=ops.get("planes")).astype(jnp.int64)
+
+        return run_pallas
+
+    fn = search.SEARCH_FNS[st.last_mile]
+
+    def run_jnp(q, ops):
+        lo, hi = predict(ops["state"], q)
+        return fn(ops["data"], q, lo, hi, st.max_err).astype(jnp.int64)
+
+    return run_jnp
 
 
 # ---------------------------------------------------------------------------
@@ -519,24 +756,21 @@ def lower(build: base.IndexBuild, data_jnp,
     )
 
 
-@register_fused("rmi")
-def _rmi_fused(plan: LookupPlan, interpret: bool) -> Callable:
-    """Whole-plan executor for RMI: the fused f32 inference kernel +
-    tiled last-mile search (`kernels/rmi_lookup`).  The f32 state is
-    refit from the plan's keys with error tables re-verified through the
-    kernel's own arithmetic, so the result is still the exact LB rank —
+def _rmi_fused_lookup(state, data, q, interpret=None, planes=None):
+    from repro.kernels.rmi_lookup import ops as rops
+
+    return rops.rmi_lookup(state, data, q, interpret=interpret,
+                           planes=planes)
+
+
+@register_fused("rmi", _rmi_fused_lookup)
+def _rmi_fused(plan: LookupPlan):
+    """Whole-plan executor for RMI: the stage-2 fetch kernel + the
+    last-mile kernel (`kernels/rmi_lookup`).  The f32 state is refit from
+    the plan's keys with error tables re-verified through the lookup's
+    own arithmetic, so the result is still the exact LB rank —
     bit-identical to every other backend."""
     from repro.kernels.rmi_lookup import ops as rops
 
-    st = plan._cache.get("_rmi_f32_state")
-    if st is None:
-        st = rops.prepare_f32_state(
-            np.asarray(plan.data),
-            branching=int(plan.meta.get("branching", 1024)))
-        plan._cache["_rmi_f32_state"] = st
-    data = plan.data
-
-    def run(q):
-        return rops.rmi_lookup(st, data, q, interpret=interpret)
-
-    return run
+    return rops.prepare_f32_state(
+        np.asarray(plan.data), branching=int(plan.meta.get("branching", 1024)))

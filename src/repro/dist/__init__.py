@@ -9,18 +9,11 @@ Three orthogonal pieces (DESIGN.md §8):
                      with exact parity against the sequential stack
   compression        int8 gradient all-reduce with error feedback
 
-`shard_map` is re-exported here behind a version shim: jax moved it from
-`jax.experimental.shard_map` to the top-level namespace, and this repo runs
-on both sides of that move.
+`shard_map` is re-exported here as `jax.shard_map`.
 """
 from __future__ import annotations
 
-import jax
-
-try:  # jax >= 0.5
-    shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+from jax import shard_map  # noqa: F401
 
 from repro.dist import compression, pipeline_parallel, sharding  # noqa: F401,E402
 

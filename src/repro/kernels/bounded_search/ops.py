@@ -1,101 +1,69 @@
-"""jit'd wrapper: bin queries to data tiles, run the kernel, un-bin.
+"""jit'd wrapper: split keys into planes, run the kernel per query chunk.
 
-Binning uses fixed per-tile capacity (GShard-style): the rare overflow
-queries fall back to the pure-jnp bounded binary search (the shared
+Windows wider than one data tile cannot be resolved from two resident
+tiles; those plans take the pure-jnp bounded binary search (the shared
 dtype-parameterized implementation in `repro.kernels.common`, run in
-int32 here), keeping the result exact for every input while the kernel
-path stays fully static-shaped.
+int32 here), so the result stays exact for every input.  The choice is
+static per plan (`kernel_serves`), so a caller can tell which path served
+its queries.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
-import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import (branchless_lower_bound, split_u64,
-                                  pad_pow2, pad_to)
+from repro.kernels.common import (branchless_lower_bound, data_planes,
+                                  key_planes, pad_pow2)
 from repro.kernels.bounded_search.kernel import DATA_TILE, lower_bound_kernel
 
-
-def _fallback_lb(data, q, lo, hi_exclusive, max_width: int):
-    """Overflow-slot fallback: shared branchless search, int32 positions."""
-    return branchless_lower_bound(
-        data, q, lo, hi_exclusive - 1, max_width, index_dtype=jnp.int32)
+#: Queries per kernel launch: bounds the scalar-memory footprint of the
+#: per-query prefetch operands and the result.
+QUERY_CHUNK = 16384
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("max_width", "capacity", "interpret"),
-)
+def kernel_serves(max_width: int) -> bool:
+    """Whether windows of ``max_width`` go to the kernel (True) or to
+    the exact jnp fallback (False)."""
+    return pad_pow2(max_width, minimum=128) <= DATA_TILE
+
+
+def kernel_planes(data):
+    """The data planes `lower_bound_windows` streams — computed once per
+    key array by callers that serve many batches against it."""
+    return data_planes(data, DATA_TILE)
+
+
+@functools.partial(jax.jit, static_argnames=("max_width", "interpret"))
 def lower_bound_windows(
     data,                 # [n] sorted uint64 (or uint32) keys
     queries,              # [m] lookup keys
     lo,                   # [m] int32/int64 window starts, LB in [lo, lo+max_width)
     max_width: int,
-    capacity: int = 256,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
+    planes=None,          # kernel_planes(data), when the caller keeps them
 ):
-    """Exact LB(q) for every query; window precondition lo <= LB < lo+max_width."""
-    # TPU is the target; the CPU backend only runs Pallas in interpret mode
-    interpret = interpret or jax.default_backend() == "cpu"
+    """Exact LB(q) for every query; window precondition lo <= LB < lo+max_width.
+
+    ``interpret=None`` runs the Mosaic kernel on TPU and the Pallas
+    interpreter elsewhere."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     n = data.shape[0]
     m = queries.shape[0]
-    window = pad_pow2(max_width, minimum=128)
-    if window > DATA_TILE:
-        # Bound too loose for the tiled kernel; stay exact via fallback.
-        hi = jnp.minimum(lo + max_width, n).astype(jnp.int32)
-        return _fallback_lb(data, queries, lo.astype(jnp.int32), hi, max_width)
-
-    n_pad = pad_to(n, DATA_TILE)
-    dhi, dlo_plane = split_u64(data)
-    pad = ((0, n_pad - n),)
-    # padding compares as +inf (all-ones), never counted as < q
-    dhi = jnp.pad(dhi, pad, constant_values=np.uint32(0xFFFFFFFF))
-    dlo_plane = jnp.pad(dlo_plane, pad, constant_values=np.uint32(0xFFFFFFFF))
-    n_tiles = n_pad // DATA_TILE
-
     lo32 = jnp.clip(lo.astype(jnp.int32), 0, max(n - 1, 0))
-    tile = lo32 // DATA_TILE                              # [m]
-    order = jnp.argsort(tile)
-    tile_s = jnp.take(tile, order)
-    # slot within tile = rank among same-tile queries
-    ranks = jnp.arange(m, dtype=jnp.int32) - jnp.searchsorted(
-        tile_s, tile_s, side="left"
-    ).astype(jnp.int32)
-    overflow = ranks >= capacity
-
-    qhi, qlo_plane = split_u64(queries)
-    qhi_s = jnp.take(qhi, order)
-    qlo_s = jnp.take(qlo_plane, order)
-    lo_s = jnp.take(lo32, order)
-    # overflow entries scatter into a trash row (n_tiles) so they can never
-    # clobber a real slot; the kernel grid only covers rows [0, n_tiles)
-    row = jnp.where(overflow, n_tiles, tile_s)
-    slot = jnp.where(overflow, 0, ranks)
-
-    def scatter(vals, fill):
-        buf = jnp.full((n_tiles + 1, capacity), fill, vals.dtype)
-        return buf.at[row, slot].set(vals)[:n_tiles]
-
-    qhi_b = scatter(qhi_s, np.uint32(0))
-    qlo_b = scatter(qlo_s, np.uint32(0))
-    lo_b = scatter(lo_s, np.int32(0))
-    valid_b = jnp.zeros((n_tiles + 1, capacity), bool).at[row, slot].set(
-        ~overflow)[:n_tiles]
-
-    pos_b = lower_bound_kernel(
-        dhi, dlo_plane, qhi_b, qlo_b, lo_b, valid_b,
-        window=window, n=n, interpret=interpret,
-    )
-    pos_s = pos_b[tile_s, slot]
-
-    # exact fallback for overflow slots
-    hi_s = jnp.minimum(lo_s + max_width, n).astype(jnp.int32)
-    q_sorted = jnp.take(queries, order)
-    fb = _fallback_lb(data, q_sorted, lo_s, hi_s, max_width)
-    pos_s = jnp.where(overflow, fb, pos_s)
-
-    out = jnp.zeros((m,), jnp.int32).at[order].set(pos_s)
-    return out
+    if not kernel_serves(max_width) or m == 0:
+        hi = jnp.minimum(lo32 + max_width, n) - 1
+        return branchless_lower_bound(data, queries, lo32, hi, max_width,
+                                      index_dtype=jnp.int32)
+    dhi, dlo = kernel_planes(data) if planes is None else planes
+    qhi, qlo = key_planes(queries)
+    tile = lo32 // DATA_TILE
+    outs = [lower_bound_kernel(tile[s:s + QUERY_CHUNK],
+                               qhi[s:s + QUERY_CHUNK], qlo[s:s + QUERY_CHUNK],
+                               lo32[s:s + QUERY_CHUNK], dhi, dlo,
+                               n=n, interpret=interpret)
+            for s in range(0, m, QUERY_CHUNK)]
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs)

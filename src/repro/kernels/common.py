@@ -11,6 +11,18 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+#: Vector lane width: the minor dimension of every kernel block.
+LANES = 128
+
+
+def block_sum(x):
+    """In-kernel int32 sum of a 2-D block, as a scalar.  Reduces to a
+    one-element vector and extracts it: Mosaic lowers a reduction
+    straight to a scalar through ``jnp.sum``, which promotes int32 to
+    int64 (unsupported in a kernel) when x64 mode is on."""
+    return jnp.squeeze(jnp.sum(x[None].astype(jnp.int32), axis=(1, 2),
+                               dtype=jnp.int32))
+
 
 def lb_steps(max_width: int) -> int:
     """Fixed trip count covering any bounded window of width <= max_width."""
@@ -73,6 +85,31 @@ def split_u64(a):
     return (a >> jnp.uint64(32)).astype(jnp.uint32), a.astype(jnp.uint32)
 
 
+def key_planes(a):
+    """Key array -> (hi, lo) int32 planes with the sign bit flipped.
+
+    Flipping the sign bit maps unsigned order onto signed order, so the
+    kernels compare keys with plain int32 compares (`less_u64`)."""
+    hi, lo = split_u64(a)
+    flip = jnp.uint32(0x80000000)
+    return (jax.lax.bitcast_convert_type(hi ^ flip, jnp.int32),
+            jax.lax.bitcast_convert_type(lo ^ flip, jnp.int32))
+
+
+def data_planes(data, tile: int):
+    """Sorted keys -> (hi, lo) `key_planes` padded to a multiple of
+    ``tile`` and shaped ``[n_pad // LANES, LANES]`` — the layout the
+    bounded-search kernel streams.  Padding holds the largest key; the
+    kernel masks it out by position anyway."""
+    n = data.shape[0]
+    pad = pad_to(max(n, 1), tile) - n
+    out = []
+    for p in key_planes(data):
+        p = jnp.pad(p, (0, pad), constant_values=np.int32(0x7FFFFFFF))
+        out.append(p.reshape(-1, LANES))
+    return tuple(out)
+
+
 def merge_u64(hi, lo):
     return (np.asarray(hi).astype(np.uint64) << np.uint64(32)) | np.asarray(
         lo
@@ -80,7 +117,8 @@ def merge_u64(hi, lo):
 
 
 def less_u64(a_hi, a_lo, b_hi, b_lo):
-    """(a < b) for keys as uint32 planes; works on jnp values in-kernel."""
+    """(a < b) for keys as (hi, lo) planes — uint32 planes, or the
+    sign-flipped int32 `key_planes`; works on jnp values in-kernel."""
     return (a_hi < b_hi) | ((a_hi == b_hi) & (a_lo < b_lo))
 
 

@@ -1,82 +1,70 @@
-"""Fused two-stage RMI inference kernel (sorted queries, prefetched tiles).
+"""RMI stage-2 model fetch: one table row per query, straight from HBM.
 
 The paper's RMI hot path is: stage-1 predict -> select stage-2 model ->
 stage-2 predict -> error bound.  The stage-2 table (up to millions of rows)
-cannot live in VMEM, and per-query HBM gathers are the slowest thing a TPU
-can do.  TPU-native form (DESIGN.md §2): the wrapper sorts queries by
-bucket, so each query block touches a narrow band of the table; a scalar-
-prefetched block index maps exactly two consecutive table tiles into VMEM
-per block, and the model gather becomes a small in-VMEM ``take``.
+cannot live in VMEM, and a per-query HBM gather is the slowest thing a TPU
+can do.  TPU-native form (DESIGN.md §2): the stage-1 bucket of each query
+is scalar-prefetched and drives the BlockSpecs, so each grid step streams
+exactly the (8, 128) block of the table that holds the query's row — one
+small DMA per query, re-used by consecutive queries of the same block.
+The row is picked out of the block by a masked sum over its bit patterns
+(exactly one lane is selected, so the sum is the row itself): no in-VMEM
+gather.
 
-All model math is float32 (TPU has no f64 path); validity is preserved by
-re-verifying the per-bucket error table through this exact f32 pipeline at
-build time (ops.prepare_f32_state) — the beyond-paper fix for the paper's
-§4.2.2 observation that 32-bit math "caused floating point errors".
+Model coefficients travel as int32 bit patterns, so the fetch is exact;
+the f32 model math runs outside the kernel through the same expression the
+error table was verified with (ops.prepare_f32_state) — the beyond-paper
+fix for the paper's §4.2.2 observation that 32-bit math "caused floating
+point errors".
 """
 from __future__ import annotations
 
-import functools
-
+import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-TABLE_TILE = 2048   # stage-2 rows per VMEM tile (3 arrays * 8 KiB)
-QUERY_BLOCK = 1024
+from repro.kernels.common import LANES, block_sum
+
+BLOCK_ROWS = 8                       # one (8, 128) vector tile per fetch
+TABLE_BLOCK = BLOCK_ROWS * LANES     # table rows per fetched block
 
 
-def _kernel(
-    tile_idx_ref,                      # scalar-prefetch: [n_blocks] int32
-    u_ref, bkt_ref,                    # [QUERY_BLOCK] f32 / int32
-    a0_ref, b0_ref, e0_ref,            # table tile t
-    a1_ref, b1_ref, e1_ref,            # table tile t+1
-    pred_ref, err_ref, ok_ref,
-):
-    g = pl.program_id(0)
-    start = tile_idx_ref[g] * TABLE_TILE
-    local = bkt_ref[...] - start                   # >= 0 (queries sorted)
-    ok = local < 2 * TABLE_TILE
-    lidx = jnp.clip(local, 0, 2 * TABLE_TILE - 1)
-    a = jnp.take(jnp.concatenate([a0_ref[...], a1_ref[...]]), lidx)
-    b = jnp.take(jnp.concatenate([b0_ref[...], b1_ref[...]]), lidx)
-    e = jnp.take(jnp.concatenate([e0_ref[...], e1_ref[...]]), lidx)
-    pred_ref[...] = a * u_ref[...] + b
-    err_ref[...] = e
-    ok_ref[...] = ok
+def _kernel(bkt_ref, a_ref, b_ref, e_ref, a_out, b_out, e_out):
+    i = pl.program_id(0)
+    # int32 operands throughout: a Python int would trace as int64 in
+    # x64 mode, which Mosaic refuses
+    k = jax.lax.rem(bkt_ref[i], np.int32(TABLE_BLOCK))   # buckets are >= 0
+    flat = (jax.lax.broadcasted_iota(jnp.int32, (BLOCK_ROWS, LANES), 0) * LANES
+            + jax.lax.broadcasted_iota(jnp.int32, (BLOCK_ROWS, LANES), 1))
+    sel = flat == k
+    for src, dst in ((a_ref, a_out), (b_ref, b_out), (e_ref, e_out)):
+        dst[i] = block_sum(jnp.where(sel, src[...], np.int32(0)))
 
 
 def rmi_infer_kernel(
-    tile_idx,                # [n_blocks] int32: table tile per query block
-    u_sorted, bkt_sorted,    # [m_pad] f32 / int32, sorted by bucket
-    a2, b2, err,             # [T_pad] f32 / f32 / int32 stage-2 table
+    bkt,                     # [m] int32 stage-2 bucket per query
+    a2, b2, err,             # [T_pad // 128, 128] int32 table (a2/b2 as f32 bits)
     *, interpret: bool = False,
 ):
-    m_pad = u_sorted.shape[0]
-    n_blocks = m_pad // QUERY_BLOCK
-    last = a2.shape[0] // TABLE_TILE - 1
-
-    q_spec = pl.BlockSpec((QUERY_BLOCK,), lambda g, s: (g,))
-    t_spec0 = pl.BlockSpec((TABLE_TILE,), lambda g, s: (s[g],))
-    t_spec1 = pl.BlockSpec(
-        (TABLE_TILE,), lambda g, s: (jnp.minimum(s[g] + 1, last),)
-    )
-
+    """``(a2[bkt], b2[bkt], err[bkt])`` per query, as int32 bit patterns."""
+    m = bkt.shape[0]
+    row = pl.BlockSpec((BLOCK_ROWS, LANES),
+                       lambda i, b: (jax.lax.div(b[i], np.int32(TABLE_BLOCK)),
+                                     np.int32(0)))
+    smem = pl.BlockSpec((m,), lambda i, b: (np.int32(0),),
+                        memory_space=pltpu.SMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n_blocks,),
-        in_specs=[q_spec, q_spec,
-                  t_spec0, t_spec0, t_spec0,
-                  t_spec1, t_spec1, t_spec1],
-        out_specs=[q_spec, q_spec, q_spec],
+        grid=(m,),
+        in_specs=[row, row, row],
+        out_specs=[smem, smem, smem],
     )
     return pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((m_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((m_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((m_pad,), jnp.bool_),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((m,), jnp.int32)] * 3,
+        name="rmi_stage2_fetch",
         interpret=interpret,
-    )(tile_idx, u_sorted, bkt_sorted, a2, b2, err, a2, b2, err)
+    )(bkt, a2, b2, err)

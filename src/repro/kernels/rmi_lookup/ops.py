@@ -1,28 +1,26 @@
 """jit'd wrappers: f32 RMI state preparation + fused lookup pipeline.
 
 ``prepare_f32_state`` re-verifies the stage-2 error table through the exact
-f32 arithmetic the kernel runs (same jnp expressions, same rounding), so
-the kernel's bounds stay valid even though TPU model math is float32 while
-the paper's reference implementations use float64.
+f32 arithmetic the lookup runs (the shared ``_stage2`` expression over the
+kernel-fetched coefficients), so the bounds stay valid even though TPU
+model math is float32 while the paper's reference implementations use
+float64.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any
+from typing import Any, Optional
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import pad_to
+from repro.kernels.common import LANES, pad_to
 from repro.kernels.rmi_lookup import ref as _ref
-from repro.kernels.rmi_lookup.kernel import (
-    QUERY_BLOCK,
-    TABLE_TILE,
-    rmi_infer_kernel,
-)
-from repro.kernels.bounded_search.ops import lower_bound_windows
+from repro.kernels.rmi_lookup.kernel import TABLE_BLOCK, rmi_infer_kernel
+from repro.kernels.bounded_search.ops import (QUERY_CHUNK,
+                                              lower_bound_windows)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -35,6 +33,7 @@ class F32RMIState:
     a2: Any
     b2: Any
     err: Any
+    tables: Any      # (a2, b2, err) as [T_pad // 128, 128] int32 kernel blocks
     scale: float
     branching: int
     n: int
@@ -42,7 +41,7 @@ class F32RMIState:
 
     def tree_flatten(self):
         leaves = (self.c0, self.c1, self.x0, self.inv_range,
-                  self.a2, self.b2, self.err)
+                  self.a2, self.b2, self.err, self.tables)
         aux = (self.scale, self.branching, self.n, self.max_err)
         return leaves, aux
 
@@ -64,6 +63,22 @@ def _infer_u_bkt(state: "F32RMIState", q):
         jnp.floor(p1 * jnp.float32(state.scale)), 0, state.branching - 1
     ).astype(jnp.int32)
     return u, bkt
+
+
+def _stage2(a, b, u):
+    """Stage-2 prediction: the ONE expression both the error-table
+    verification and the lookup evaluate."""
+    return a * u + b
+
+
+def _kernel_tables(a2f, b2f, err_i):
+    """Stage-2 table -> the kernel's int32 blocks (f32 as bit patterns)."""
+    pad = pad_to(len(err_i), TABLE_BLOCK) - len(err_i)
+
+    def block(x):
+        return jnp.asarray(np.pad(x.view(np.int32), (0, pad)).reshape(-1, LANES))
+
+    return (block(a2f), block(b2f), block(err_i))
 
 
 def prepare_f32_state(keys: np.ndarray, branching: int = 4096) -> F32RMIState:
@@ -92,7 +107,7 @@ def prepare_f32_state(keys: np.ndarray, branching: int = 4096) -> F32RMIState:
         c0=jnp.float32(a1), c1=jnp.float32(b1), x0=jnp.float32(x0),
         inv_range=jnp.float32(inv_range),
         a2=jnp.zeros(1, jnp.float32), b2=jnp.zeros(1, jnp.float32),
-        err=jnp.zeros(1, jnp.int32),
+        err=jnp.zeros(1, jnp.int32), tables=(),
         scale=scale, branching=B, n=n, max_err=1,
     )
     # bucket assignment + u through the EXACT kernel-side math
@@ -121,7 +136,7 @@ def prepare_f32_state(keys: np.ndarray, branching: int = 4096) -> F32RMIState:
 
     # error verification through f32 arithmetic (same expression as kernel)
     pred = np.asarray(
-        jax.jit(lambda a, b, u, k: jnp.take(a, k) * u + jnp.take(b, k))(
+        jax.jit(lambda a, b, u, k: _stage2(jnp.take(a, k), jnp.take(b, k), u))(
             jnp.asarray(a2f), jnp.asarray(b2f), u_j, jnp.asarray(bkt, jnp.int32)
         ),
         np.float64,
@@ -134,7 +149,7 @@ def prepare_f32_state(keys: np.ndarray, branching: int = 4096) -> F32RMIState:
 
     def _eval(bids, kidx):
         return np.asarray(
-            jax.jit(lambda a, b, u, k: jnp.take(a, k) * u + jnp.take(b, k))(
+            jax.jit(lambda a, b, u, k: _stage2(jnp.take(a, k), jnp.take(b, k), u))(
                 jnp.asarray(a2f), jnp.asarray(b2f),
                 u_j[jnp.asarray(kidx)], jnp.asarray(bids, jnp.int32)
             ),
@@ -155,55 +170,35 @@ def prepare_f32_state(keys: np.ndarray, branching: int = 4096) -> F32RMIState:
     state = dataclasses.replace(
         state,
         a2=jnp.asarray(a2f), b2=jnp.asarray(b2f), err=jnp.asarray(err_i),
+        tables=_kernel_tables(a2f, b2f, err_i),
         max_err=int(2 * err_i.max() + 2),
     )
     return state
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def rmi_bounds(state: F32RMIState, queries, interpret: bool = False):
-    """Fused inference via the Pallas kernel: queries -> (lo, hi)."""
-    interpret = interpret or jax.default_backend() == "cpu"
+def rmi_bounds(state: F32RMIState, queries, interpret: Optional[bool] = None):
+    """Stage-2 models fetched by the Pallas kernel: queries -> (lo, hi)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     m = queries.shape[0]
     u, bkt = _infer_u_bkt(state, queries)
-
-    m_pad = pad_to(max(m, 1), QUERY_BLOCK)
-    order = jnp.argsort(bkt)
-    u_s = jnp.pad(jnp.take(u, order), (0, m_pad - m))
-    bkt_s = jnp.pad(jnp.take(bkt, order), (0, m_pad - m))
-
-    T_pad = pad_to(state.branching, TABLE_TILE)
-    a2 = jnp.pad(state.a2, (0, T_pad - state.branching))
-    b2 = jnp.pad(state.b2, (0, T_pad - state.branching))
-    er = jnp.pad(state.err, (0, T_pad - state.branching))
-
-    n_blocks = m_pad // QUERY_BLOCK
-    tile_idx = (
-        bkt_s[:: QUERY_BLOCK].astype(jnp.int32) // TABLE_TILE
-    ).reshape(n_blocks)
-
-    pred_s, err_s, ok_s = rmi_infer_kernel(
-        tile_idx, u_s, bkt_s, a2, b2, er, interpret=interpret
-    )
-    # fallback for blocks whose buckets span > 2 table tiles (rare)
-    fb_pred = jnp.take(state.a2, jnp.minimum(bkt_s, state.branching - 1)) * u_s \
-        + jnp.take(state.b2, jnp.minimum(bkt_s, state.branching - 1))
-    fb_err = jnp.take(state.err, jnp.minimum(bkt_s, state.branching - 1))
-    pred_s = jnp.where(ok_s, pred_s, fb_pred)
-    err_s = jnp.where(ok_s, err_s, fb_err)
-
-    pred = jnp.zeros((m,), jnp.float32).at[order].set(pred_s[:m])
+    parts = [rmi_infer_kernel(bkt[s:s + QUERY_CHUNK], *state.tables,
+                              interpret=interpret)
+             for s in range(0, m, QUERY_CHUNK)]
+    a_bits, b_bits, err = (p[0] if len(parts) == 1 else jnp.concatenate(p)
+                           for p in zip(*parts))
+    pred = _stage2(jax.lax.bitcast_convert_type(a_bits, jnp.float32),
+                   jax.lax.bitcast_convert_type(b_bits, jnp.float32), u)
     pred = jnp.clip(pred, -1.0, float(state.n) + 1.0)  # guard int32 overflow
-    err = jnp.zeros((m,), jnp.int32).at[order].set(err_s[:m])
     lo = jnp.clip(jnp.floor(pred).astype(jnp.int32) - err, 0, state.n)
     hi = jnp.clip(jnp.ceil(pred).astype(jnp.int32) + err, 0, state.n)
     return lo, hi
 
 
-def rmi_lookup(state: F32RMIState, data, queries, interpret: bool = False):
-    """End-to-end: fused RMI bounds -> tiled last-mile search -> exact LB."""
-    lo, hi = rmi_bounds(state, queries, interpret=interpret)
-    del hi
-    return lower_bound_windows(
-        data, queries, lo, max_width=state.max_err, interpret=interpret
-    )
+def rmi_lookup(state: F32RMIState, data, queries,
+               interpret: Optional[bool] = None, planes=None):
+    """End-to-end: kernel-fetched RMI bounds -> last-mile search -> exact LB."""
+    lo, _hi = rmi_bounds(state, queries, interpret=interpret)
+    return lower_bound_windows(data, queries, lo, max_width=state.max_err,
+                               interpret=interpret, planes=planes)

@@ -149,8 +149,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # jax 0.4.x returns a one-element list
-        cost = cost[0]
     hlo_text = compiled.as_text()
     attributed = hlo_cost.analyze(hlo_text)   # trip-count-aware, per-device
     coll_naive = collective_bytes(hlo_text)   # body-once (sanity column)

@@ -18,9 +18,3 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_mesh(shape, axes):
     """Elastic path: arbitrary shapes after fault-tolerance re-planning."""
     return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-# TPU v5e hardware constants used by the roofline analysis (benchmarks/).
-PEAK_FLOPS_BF16 = 197e12       # per chip
-HBM_BW = 819e9                 # bytes/s per chip
-ICI_BW = 50e9                  # bytes/s per link (~4 links usable per chip)

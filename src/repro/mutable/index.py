@@ -80,13 +80,6 @@ class MutableView:
         """Device merged lookup; `q` is a jnp/np uint64 batch."""
         return self.merged_fn(q, self.delta.device)
 
-    def scan_fn(self, m: int) -> Callable:
-        """Merged-view scan executable ``(q, delta) -> (pos, window)`` —
-        the plan's `compile_merged_scan` transform, cached per
-        (m, backend) on the generation's plan."""
-        return self.generation.plan.compile_merged_scan(
-            m, backend=self.generation.backend)
-
     @property
     def n_keys(self) -> int:
         """Logical key count of the merged view."""
@@ -244,8 +237,6 @@ class MutableIndex:
         set.  Returns the new generation, or None if the delta was
         empty or the rebuild was abandoned.
         """
-        import jax.numpy as jnp
-
         with self._compact_mu:
             snap = self.view()
             if snap.delta.count == 0:
@@ -269,7 +260,7 @@ class MutableIndex:
             else:
                 new_spec = self.spec
                 build = spec_mod.build(new_spec, merged_keys)
-            data = jnp.asarray(merged_keys)
+            data = self.registry.place_keys(merged_keys)
             with self._mu:
                 if self._view.generation is not snap.generation:
                     return None   # reset() raced the rebuild: stale, drop it

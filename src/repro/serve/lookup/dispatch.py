@@ -4,14 +4,16 @@ Generalizes mode (c) of `benchmarks/parallel_scaling.py` into a reusable
 engine.  The query batch is padded to a power-of-two bucket (a multiple
 of the shard count), placed over the mesh's data axis through the
 `repro.dist.sharding` activation rule for the logical `batch` axis, and
-run through a `repro.core.plan.LookupPlan` executable — the dispatcher
-shards PLANS, not hand-rolled closures: pass a plan and it compiles (and
-caches) the lookup for the requested backend, or pass any jitted
-callable (e.g. a merged-view or scan executable) directly.  jit picks
-the partitioning up from the input sharding, so the very same compiled
-lookup serves 1 or N devices; the index state and the key array stay
-replicated (they are the small side — the paper's learned indexes are
-KB–MB against GB of data).
+run through a `repro.core.plan.LookupPlan` program — the dispatcher
+shards PLANS, not hand-rolled closures: pass a plan and it runs the
+plan's program for the requested backend with the plan's operands (key
+array + index state) replicated over the mesh, or pass any callable
+(e.g. a merged-view or scan executable with its operands bound)
+directly.  The mesh's axes are `Auto`, so jit picks the partitioning of
+the jnp lookup up from the input sharding; Pallas kernels run per
+device under `jax.shard_map` (`LookupPlan.program(mesh=...)`).  The
+very same program serves 1 or N devices; every device holds the key
+array and the index state.
 
 Bit-exactness: every lane of the plan pipeline is an independent
 gather/compare chain over the same replicated arrays, so the sharded
@@ -30,6 +32,7 @@ from typing import Any, Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import plan as plan_mod
 from repro.dist import sharding as SH
@@ -48,10 +51,14 @@ def make_plan(build, data_jnp, last_mile: Optional[str] = None):
     return plan_mod.lower(build, data_jnp, last_mile=last_mile)
 
 
-def data_axis_mesh():
-    """1-D mesh over every local device, axis named `data` — the serving
-    analogue of the production mesh's data axis (launch/mesh.py)."""
-    return jax.make_mesh((len(jax.devices()),), ("data",))
+def data_axis_mesh(devices=None):
+    """1-D mesh over ``devices`` (default: every local device), axis
+    named `data`.  Its axis type is `Auto`: the jnp lookup's gathers from
+    the replicated key array are partitioned by the compiler from the
+    query sharding, which an `Explicit` axis (the default of
+    `jax.make_mesh`) would refuse."""
+    devices = list(jax.devices() if devices is None else devices)
+    return Mesh(np.array(devices), ("data",), axis_types=(AxisType.Auto,))
 
 
 class ShardedDispatcher:
@@ -60,6 +67,9 @@ class ShardedDispatcher:
     def __init__(self, mesh=None, pad_quantum: int = PAD_QUANTUM,
                  recorder=None):
         self.mesh = data_axis_mesh() if mesh is None else mesh
+        #: placement of everything a batch reads besides itself (key
+        #: array, index state): one copy on every device of the mesh
+        self.replicated = NamedSharding(self.mesh, P())
         self.pad_quantum = int(pad_quantum)
         # one rule walk for everyone: the dist layer owns the policy
         self.n_shards = SH.dispatch_groups(mesh=self.mesh,
@@ -93,6 +103,11 @@ class ShardedDispatcher:
         """The placement of a padded query batch — one rule walk through
         the dist layer (also what AOT executable lowering keys on)."""
         return SH.act_sharding((p,), ("batch",), self.mesh)
+
+    def place_operands(self, ops):
+        """Replicate a program's operand pytree over the mesh (a no-op
+        for arrays already placed there)."""
+        return jax.device_put(ops, self.replicated)
 
     def place(self, q_padded: np.ndarray):
         """Device-put one already-padded batch over the data axis."""
@@ -162,8 +177,9 @@ class ShardedDispatcher:
 
     def __call__(self, fn, keys: np.ndarray, backend: str = "jnp",
                  n_valid_arg: bool = False):
-        """Run a plan (compiled on demand for ``backend``) or any jitted
-        lookup callable on `keys`, synchronously: launch then finalize.
+        """Run a plan (its ``backend`` lookup program, operands replicated
+        over the mesh) or any lookup callable on `keys`, synchronously:
+        launch then finalize.
 
         Returns int64 positions for plain lookups; executables that
         return a tuple (e.g. a plan's scan: positions + record window)
@@ -175,7 +191,9 @@ class ShardedDispatcher:
         from repro.obs.trace import maybe_span
 
         if isinstance(fn, plan_mod.LookupPlan):
-            fn = fn.compile(backend=backend)
+            fn = plan_mod.BoundProgram(
+                fn.program("lb", backend=backend, mesh=self.mesh),
+                self.place_operands(fn.operands(backend)))
         keys = np.asarray(keys, dtype=np.uint64)
         with maybe_span(self.recorder, "pad_place", cat="serve",
                         n_keys=int(keys.size)):
@@ -194,10 +212,11 @@ class ShardedDispatcher:
 class RoutedContext:
     """Everything one routed batch pins at dispatch time.
 
-    ``lane_ctxs[s][r]`` is the executor context (read/scan executables +
-    cache key) of replica ``r`` of shard ``s`` — the executable cache
-    keys on ``(shard generation version, replica)``, so AOT executables
-    stay committed to their lane's device.  Fields are intentionally
+    ``lane_ctxs[s][r]`` is the executor context (read/scan programs, the
+    operands placed on the lane's device, cache key) of replica ``r`` of
+    shard ``s`` — the executable cache keys on ``(shard generation
+    version, replica)``, so AOT executables stay committed to their
+    lane's device.  Fields are intentionally
     untyped: the executor imports this class, not the other way round.
     """
 
@@ -290,7 +309,7 @@ class RoutedDispatcher:
         groups = SH.shard_replica_groups(self._devices, topology.replicas)
         self.lanes = tuple(
             tuple(ShardedDispatcher(
-                mesh=jax.sharding.Mesh(np.array([dev]), ("data",)),
+                mesh=data_axis_mesh([dev]),
                 pad_quantum=self.pad_quantum, recorder=self.recorder)
                 for dev in grp)
             for grp in groups)
@@ -351,8 +370,9 @@ class RoutedDispatcher:
         `_RoutedHandle` (completion is the handle's ``finalize``).
 
         ``exec_cache`` (async path) resolves each lane's AOT executable;
-        without it (sync path) the lane context's jitted callables run
-        directly.  Empty shards launch nothing.
+        without it (sync path) the lane context's jitted programs run
+        directly.  Either way the lane's operands (``bind``) follow the
+        query.  Empty shards launch nothing.
         """
         from repro.obs.trace import maybe_span
 
@@ -386,8 +406,8 @@ class RoutedDispatcher:
                 exe = exec_cache.get(ctx, kind, aux, p, make_fn, lane)
             else:
                 exe = make_fn()
-            out = exe(qj, np.int32(c)) if instr else exe(qj)
-            subs.append((s, out))
+            args = (np.int32(c),) if instr else ()
+            subs.append((s, exe(qj, *args, *ctx.bind)))
         return _RoutedHandle(subs, order, counts, padded, m, kind,
                              instr, rctx)
 

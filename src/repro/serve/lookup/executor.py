@@ -63,16 +63,18 @@ class AsyncContext:
     ``key`` namespaces the cache: everything the compiled program
     depends on beyond operand shapes — the generation version (and, for
     merged mutable views, the padded delta length, a compile-shape
-    axis).  ``bind`` holds extra device operands appended after the
-    query batch (the padded delta for merged lookups); they vary per
-    view without invalidating the cached executable, which is exactly
-    why the merged fn takes the delta as an ARGUMENT, not a closure.
+    axis).  ``bind`` holds the device operands appended after the query
+    batch: the plan's operand pytree (key array + index state, placed
+    for the dispatcher), preceded by the padded delta for merged
+    lookups.  They are ARGUMENTS, not closure constants: the compiled
+    program embeds no key data, and a new delta or a new generation of
+    the same shape runs the same program.
     """
 
     key: Tuple                 # hashable; key[0] is the generation version
     read_fn: Callable          # (q, *bind) -> positions
     scan_fn: Callable          # m -> ((q, *bind) -> (positions, window))
-    bind: Tuple = ()           # device operands appended after q
+    bind: Tuple = ()           # device operands appended after q (pytrees)
     sample_key: int = 1        # a valid key for warm-up dummy batches
     #: Health telemetry (DESIGN.md §15): when set, ``read_fn`` is the
     #: plan's instrumented executable ``(q, n_valid, *bind) -> (pos,
@@ -164,29 +166,33 @@ class ExecutableCache:
     def _build(fn, bucket: int, bind: Tuple, dispatcher,
                instrumented: bool = False):
         """AOT-lower ``fn`` for the padded bucket (batch-sharded query +
-        replicated bind operands) when it supports `.lower`; otherwise
-        return the callable unchanged (jit wrappers carry their own
-        per-shape cache; injected plain callables just run).
-        Instrumented executables take the real batch size as a dynamic
-        int32 scalar between the query and the bind operands — ONE
-        compiled program per bucket, not one per occupancy."""
+        bind operands where they are placed) when it supports `.lower`;
+        otherwise return the callable unchanged (injected plain
+        callables just run).  Instrumented executables take the real
+        batch size as a dynamic int32 scalar between the query and the
+        bind operands — ONE compiled program per bucket, not one per
+        occupancy.  A compile failure raises: it fails the batch (or the
+        warm-up) that asked for it, and is never served around."""
         import jax
         import jax.numpy as jnp
 
         lower = getattr(fn, "lower", None)
         if lower is None:
             return fn
-        try:
-            sds_q = jax.ShapeDtypeStruct(
-                (bucket,), jnp.uint64,
-                sharding=dispatcher.query_sharding(bucket))
-            sds_args = ([jax.ShapeDtypeStruct((), jnp.int32)]
-                        if instrumented else [])
-            sds_args += [jax.ShapeDtypeStruct(b.shape, b.dtype)
-                         for b in bind]
-            return lower(sds_q, *sds_args).compile()
-        except Exception:   # noqa: BLE001 — AOT is an optimization only
-            return fn
+
+        def sds(x):
+            sharding = getattr(x, "sharding", None)
+            if getattr(x, "committed", True) is False:
+                sharding = None     # uncommitted: follows the query
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+        sds_q = jax.ShapeDtypeStruct(
+            (bucket,), jnp.uint64,
+            sharding=dispatcher.query_sharding(bucket))
+        sds_args = ([jax.ShapeDtypeStruct((), jnp.int32)]
+                    if instrumented else [])
+        sds_args += [jax.tree.map(sds, b) for b in bind]
+        return lower(sds_q, *sds_args).compile()
 
     def get(self, ctx: AsyncContext, kind: str, aux: int, bucket: int,
             make_fn: Callable, dispatcher, warm: bool = False):

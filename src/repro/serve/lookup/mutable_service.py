@@ -131,16 +131,26 @@ class MutableLookupService(LookupService):
         view = self.mindex.view()
         delta_dev = view.delta.device
         gen = view.generation
-
-        def scan_for(m: int):
-            fn = view.scan_fn(m)
-            return lambda q: fn(q, delta_dev)
+        read, scan_for, ops = self._merged_programs(gen)
 
         if self.health is not None:
-            ifn = gen.instrumented_merged_fn()
-            return (lambda q, n_valid: ifn(q, n_valid, delta_dev),
-                    scan_for, gen.version)
-        return view.lookup, scan_for, gen.version
+            return (lambda q, n_valid: read(q, n_valid, delta_dev, ops),
+                    lambda m: (lambda q: scan_for(m)(q, delta_dev, ops)),
+                    gen.version)
+        return (lambda q: read(q, delta_dev, ops),
+                lambda m: (lambda q: scan_for(m)(q, delta_dev, ops)),
+                gen.version)
+
+    def _merged_programs(self, gen: Generation):
+        """``(read program, m -> scan program, operands)`` over the
+        merged (base + delta) view: the delta is an argument between the
+        query (and ``n_valid``) and the operands."""
+        mesh = self.dispatcher.mesh
+        read = gen.program("instr_merged" if self.health is not None
+                           else "merged", mesh=mesh)
+        return (read,
+                lambda m: gen.program("merged_scan", m=int(m), mesh=mesh),
+                gen.operands_on(self.dispatcher))
 
     def _insert_apply(self, run) -> np.ndarray:
         """Land one insert run in the delta (host-side, in admission
@@ -194,15 +204,14 @@ class MutableLookupService(LookupService):
         pow2 pad-boundary crossing is a (correct, observable) miss."""
         view = self.mindex.view()
         delta_dev = view.delta.device
-        instrumented = self.health is not None
+        read, scan_for, ops = self._merged_programs(view.generation)
         return AsyncContext(
             key=(view.generation.version, int(delta_dev.shape[0])),
-            read_fn=(view.generation.instrumented_merged_fn()
-                     if instrumented else view.merged_fn),
-            scan_fn=view.scan_fn,
-            bind=(delta_dev,),
+            read_fn=read,
+            scan_fn=scan_for,
+            bind=(delta_dev, ops),
             sample_key=int(np.asarray(view.generation.data[:1])[0]),
-            instrumented=instrumented)
+            instrumented=self.health is not None)
 
     def _async_work_items(self, batch):
         """Re-pin PER RUN (the sync `_process_batch` contract): an

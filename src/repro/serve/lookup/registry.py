@@ -3,8 +3,10 @@
 A `Generation` is one fully-built, immutable serving unit: the
 `IndexBuild` (state pytree + interpreting functions), the device copy of
 the sorted key array, the `LookupPlan` the build lowers to, and the
-plan-compiled lookup for the generation's backend.  The registry's only
-mutable cell is a name -> Generation pointer; `publish` replaces that
+plan-compiled lookup for the generation's backend.  Where the key array
+lives is the owning service's call (`IndexRegistry.placement`): on every
+device of a broadcast mesh, or on the device of a routed shard's lane.
+The registry's only mutable cell is a name -> Generation pointer; `publish` replaces that
 pointer AFTER the build completes, so a reader can observe the old
 generation or the new one, never a half-built one.  Swapping does not
 drain in-flight batches: a dispatched batch pins the generation it was
@@ -57,28 +59,30 @@ class Generation:
     #: Shard index inside a RoutedGeneration (None for broadcast
     #: generations) — threaded into per-shard health records.
     shard: Optional[int] = None
+    #: operands placed per dispatcher placement (`operands_on`)
+    _placed: dict = dataclasses.field(default_factory=dict, repr=False,
+                                      compare=False)
 
-    def scan_fn(self, m: int) -> Callable:
-        """Plan-compiled scan (positions + m-record window), cached on
-        the plan per (m, backend) — op kind "scan" dispatches here."""
-        return self.plan.compile_scan(m, backend=self.backend)
+    def program(self, kind: str, m: Optional[int] = None, mesh=None,
+                donate: bool = False) -> Callable:
+        """The plan's jitted program of one op kind on this generation's
+        backend (`LookupPlan.program`); its last argument is
+        `operands_on(...)`.  Shared by every generation of the same
+        shape, so a hot-swap to one re-uses the compiled program."""
+        return self.plan.program(kind, backend=self.backend, m=m,
+                                 mesh=mesh, donate=donate)
 
-    def fn_for(self, donate: bool = False) -> Callable:
-        """Plan-compiled lookup, optionally donating the query buffer
-        (safe on the dispatcher's staged placements; no-op on CPU)."""
-        return self.plan.compile(backend=self.backend, donate=donate)
-
-    def instrumented_fn(self, donate: bool = False) -> Callable:
-        """Plan-compiled instrumented lookup ``(q, n_valid) -> (LB,
-        health stats)`` — same positions as ``fn`` bit-for-bit, plus the
-        device-reduced stats the health monitor folds in."""
-        return self.plan.compile_instrumented(backend=self.backend,
-                                              donate=donate)
-
-    def instrumented_merged_fn(self) -> Callable:
-        """Instrumented merged-view lookup ``(q, n_valid, delta) ->
-        (merged LB, base-plan health stats)`` for the mutable service."""
-        return self.plan.compile_instrumented_merged(backend=self.backend)
+    def operands_on(self, dispatcher) -> Any:
+        """The plan's operands (key array + index state) placed the way
+        ``dispatcher`` runs batches — replicated over its mesh — once per
+        placement."""
+        key = dispatcher.replicated
+        ops = self._placed.get(key)
+        if ops is None:
+            ops = dispatcher.place_operands(
+                self.plan.operands(self.backend))
+            self._placed[key] = ops
+        return ops
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -99,7 +103,6 @@ class RoutedGeneration:
     shards: Tuple[Generation, ...]
     spec: Optional[spec_mod.IndexSpec] = None
     backend: str = "jnp"
-    _scan_cache: dict = dataclasses.field(default_factory=dict, repr=False)
 
     @property
     def n_keys(self) -> int:
@@ -130,38 +133,15 @@ class RoutedGeneration:
         spill when every shard holds at least ``m`` keys."""
         return self.topology.min_shard_len
 
-    def shard_scan_fn(self, s: int, m: int) -> Callable:
-        """Scan for shard ``s``: the shard-local window merged with the
-        head of shard ``s+1``.  All shard-s records sort strictly below
-        all shard-(s+1) records (boundaries are snapped to duplicate
-        runs), so the first ``m`` of the sorted union is exactly the
-        global window — the same argument as the delta merged scan."""
-        key = (int(s), int(m))
-        fn = self._scan_cache.get(key)
-        if fn is not None:
-            return fn
-        gen = self.shards[s]
+    def spill(self, s: int, length: int):
+        """The head of shard ``s+1`` (None for the last shard): what a
+        shard-s scan window is completed from (`LookupPlan.scan_expr`).
+        All shard-s records sort strictly below all shard-(s+1) records
+        (boundaries are snapped to duplicate runs), so the first ``m`` of
+        the sorted union is exactly the global window."""
         if s == len(self.shards) - 1:
-            fn = gen.scan_fn(m)          # sentinel padding is global here
-        else:
-            import jax
-            from repro.core.plan import _window_gather
-
-            run = gen.plan.lb_expr(backend=gen.backend)
-            data = gen.plan.data
-            head = self.shards[s + 1].data[:m]
-
-            def scan(q):
-                pos = run(q)
-                wb = _window_gather(data, pos, m)
-                spill = jnp.broadcast_to(head[None, :], (q.shape[0], m))
-                merged = jnp.sort(
-                    jnp.concatenate([wb, spill], axis=1), axis=1)[:, :m]
-                return pos, merged
-
-            fn = jax.jit(scan)
-        self._scan_cache[key] = fn
-        return fn
+            return None
+        return self.shards[s + 1].data[:length]
 
 
 class IndexRegistry:
@@ -180,6 +160,17 @@ class IndexRegistry:
         #: keyed by version, so stats from a batch that completes against
         #: a just-retired generation still land in ITS record.
         self.health = None
+        #: optional ``(keys, shard, topology) -> device array`` (set by
+        #: the owning service): where a new generation's key array goes.
+        #: None keeps the default device.
+        self.placement = None
+
+    def place_keys(self, keys: np.ndarray, shard: Optional[int] = None,
+                   topology: Optional[ShardTopology] = None):
+        """Device copy of one generation's sorted keys (`placement`)."""
+        if self.placement is None:
+            return jnp.asarray(keys)
+        return self.placement(keys, shard, topology)
 
     def subscribe(self, callback) -> None:
         """Register ``callback(name, generation)`` to run after every
@@ -332,7 +323,7 @@ class IndexRegistry:
                 b = builds[s] if builds[s] is not None \
                     else spec_mod.build(shard_specs[s], sl)
                 gens.append(self.make_generation(
-                    b, jnp.asarray(sl),
+                    b, self.place_keys(sl, shard=s, topology=topology),
                     last_mile=shard_specs[s].last_mile,
                     backend=shard_specs[s].backend,
                     spec=shard_specs[s], shard=s))
@@ -359,7 +350,7 @@ class IndexRegistry:
         with maybe_span(self.recorder, "index_build", cat="lifecycle",
                         reg_name=name, index=sp.index, n_keys=int(keys.size)):
             build = spec_mod.build(sp, keys)
-            data = jnp.asarray(keys)
+            data = self.place_keys(keys)
         return self.publish(build, data, name=name, last_mile=sp.last_mile,
                             backend=sp.backend, spec=sp)
 

@@ -36,6 +36,8 @@ from typing import Any, Dict, Optional, Tuple
 import numpy as np
 
 from repro.core import spec as spec_mod
+from repro.core.plan import BoundProgram
+from repro.dist import sharding as SH
 from repro.obs.alerts import AlertEngine, AlertRule, default_rules
 from repro.obs.health import HealthMonitor
 from repro.obs.trace import SpanRecorder, maybe_span
@@ -194,6 +196,17 @@ class LookupService:
         self.dispatcher = ShardedDispatcher(
             mesh=mesh, pad_quantum=self.cfg.pad_quantum,
             recorder=self.recorder)
+        #: the broadcast dispatcher's mesh bounds every placement: key
+        #: arrays go onto these devices only (a routed topology splits
+        #: them into lanes), so a one-device mesh keeps the service on
+        #: one chip of a larger host
+        self._broadcast = self.dispatcher
+        self._devices = list(self.dispatcher.mesh.devices.flat)
+        self.registry.placement = self._place_keys
+        #: hot-swap re-warms that failed (`_warm_retry`); a failed warm
+        #: leaves first-touch compiles on the serving path
+        self.warm_failures = 0
+        self.last_warm_error: Optional[BaseException] = None
         self.metrics = ServiceMetrics(
             slo_p99_ms=self.cfg.slo_p99_ms,
             window_slot_s=self.cfg.window_slot_s,
@@ -251,6 +264,20 @@ class LookupService:
             return ShardTopology.from_keys(keys, self.cfg.shards,
                                            self.cfg.replicas)
         return None
+
+    def _place_keys(self, keys: np.ndarray, shard: Optional[int],
+                    topology: Optional[ShardTopology]):
+        """Registry placement hook: a broadcast generation's keys go onto
+        every device of the mesh; routed shard ``s``'s keys onto the
+        device of its first lane (`RoutedDispatcher` assigns lanes with
+        the same `shard_replica_groups` walk; other replicas copy from
+        there when their lane context is built)."""
+        import jax
+
+        if shard is None:
+            return jax.device_put(keys, self._broadcast.replicated)
+        groups = SH.shard_replica_groups(self._devices, topology.replicas)
+        return jax.device_put(keys, groups[shard][0])
 
     def swap_keys(self, keys: np.ndarray) -> Generation:
         """Rebuild on a fresh key set and hot-swap it in (no draining).
@@ -390,9 +417,21 @@ class LookupService:
         gen = self.registry.current()
         if isinstance(gen, RoutedGeneration):
             return self._routed_context(gen)
-        if self.health is not None:
-            return gen.instrumented_fn(), gen.scan_fn, gen.version
-        return gen.fn, gen.scan_fn, gen.version
+        read, scan_for, ops = self._programs(gen)
+        return (BoundProgram(read, ops),
+                lambda m: BoundProgram(scan_for(m), ops), gen.version)
+
+    def _programs(self, gen: Generation, dispatcher=None, donate=False):
+        """``(read program, m -> scan program, operands)`` of one
+        generation on one dispatcher: the read program is the
+        instrumented one when health is on; the operands are the
+        generation's key array and index state placed the way the
+        dispatcher runs batches."""
+        d = self.dispatcher if dispatcher is None else dispatcher
+        read = gen.program("instr" if self.health is not None else "lb",
+                           mesh=d.mesh, donate=donate)
+        return (read, lambda m: gen.program("scan", m=int(m), mesh=d.mesh),
+                gen.operands_on(d))
 
     def _routed_context(self, gen: RoutedGeneration) -> RoutedContext:
         """One executable-cache-addressable context per (generation,
@@ -404,19 +443,23 @@ class LookupService:
         rctx = self._rctx_cache.get(key)
         if rctx is not None:
             return rctx
+        spill_len = min(self.cfg.max_scan_length, gen.max_scan_len)
         lane_ctxs = []
         for s, sgen in enumerate(gen.shards):
             reps = []
-            read_fn = (sgen.instrumented_fn(donate=self._donate)
-                       if instrumented else sgen.fn_for(self._donate))
-            scan_fn = (lambda m, s=s, g=gen: g.shard_scan_fn(s, int(m)))
-            for r in range(len(self.dispatcher.lanes[s])):
+            spill = gen.spill(s, spill_len)
+            sample_key = int(np.asarray(sgen.data[:1])[0])
+            for r, lane in enumerate(self.dispatcher.lanes[s]):
+                read_fn, scan_fn, ops = self._programs(sgen, lane,
+                                                       donate=self._donate)
+                if spill is not None:
+                    ops = {**ops, "spill": lane.place_operands(spill)}
                 reps.append(AsyncContext(
                     key=(sgen.version, r),
                     read_fn=read_fn,
                     scan_fn=scan_fn,
-                    bind=(),
-                    sample_key=int(np.asarray(sgen.data[:1])[0]),
+                    bind=(ops,),
+                    sample_key=sample_key,
                     instrumented=instrumented))
             lane_ctxs.append(tuple(reps))
         rctx = RoutedContext(
@@ -527,14 +570,14 @@ class LookupService:
         gen = self.registry.current()
         if isinstance(gen, RoutedGeneration):
             return self._routed_context(gen)
-        instrumented = self.health is not None
+        read_fn, scan_fn, ops = self._programs(gen)
         return AsyncContext(
             key=(gen.version,),
-            read_fn=gen.instrumented_fn() if instrumented else gen.fn,
-            scan_fn=gen.scan_fn,
-            bind=(),
+            read_fn=read_fn,
+            scan_fn=scan_fn,
+            bind=(ops,),
             sample_key=int(np.asarray(gen.data[:1])[0]),
-            instrumented=instrumented)
+            instrumented=self.health is not None)
 
     def _async_work_items(self, batch):
         """Lazily yield `WorkItem`s for one taken batch, in admission
@@ -627,7 +670,8 @@ class LookupService:
         if isinstance(gen, RoutedGeneration):
             if not isinstance(self.dispatcher, RoutedDispatcher):
                 self.dispatcher = RoutedDispatcher(
-                    gen.topology, pad_quantum=self.cfg.pad_quantum,
+                    gen.topology, devices=self._devices,
+                    pad_quantum=self.cfg.pad_quantum,
                     recorder=self.recorder)
             else:
                 self.dispatcher.set_replicas(gen.topology)
@@ -683,15 +727,19 @@ class LookupService:
     def _warm_retry(self) -> None:
         """Warm the current context, tolerating construction windows
         (the mutable service publishes its first generation before its
-        view pointer exists — retry briefly, then give up quietly: a
-        missed warm only costs one first-touch compile per bucket)."""
+        view pointer exists — retry briefly).  A warm that still fails is
+        counted in ``warm_failures`` with its error kept in
+        ``last_warm_error``: serving goes on with first-touch compiles,
+        and a caller that must not (the chip smoke) checks the count."""
         deadline = time.perf_counter() + 5.0
         while True:
             try:
                 self.warm_now()
                 return
-            except Exception:   # noqa: BLE001 — warm-up is best-effort
+            except Exception as e:   # noqa: BLE001 — counted, not fatal
                 if time.perf_counter() >= deadline:
+                    self.warm_failures += 1
+                    self.last_warm_error = e
                     return
                 time.sleep(0.005)
 

@@ -13,12 +13,6 @@ def _body(x, w):
     return jnp.tanh(x @ w), None
 
 
-def _xla_cost(c):
-    """compiled.cost_analysis() returns a one-element list on jax 0.4.x."""
-    cost = c.cost_analysis()
-    return cost[0] if isinstance(cost, list) else cost
-
-
 def test_scan_trip_counts_recovered():
     def scanned(x, ws):
         x, _ = jax.lax.scan(_body, x, ws)
@@ -31,7 +25,7 @@ def test_scan_trip_counts_recovered():
     expected = 6 * 2 * 64 * 256 * 256
     assert r["flops"] == pytest.approx(expected, rel=1e-6)
     # and the naive xla counter under-reports by exactly the trip count
-    assert _xla_cost(c)["flops"] == pytest.approx(expected / 6, rel=1e-6)
+    assert c.cost_analysis()["flops"] == pytest.approx(expected / 6, rel=1e-6)
 
 
 def test_unrolled_matches_xla():
@@ -44,7 +38,7 @@ def test_unrolled_matches_xla():
     ws = jax.ShapeDtypeStruct((4, 128, 128), jnp.float32)
     c = jax.jit(unrolled).lower(x, ws).compile()
     r = hlo_cost.analyze(c.as_text())
-    assert r["flops"] == pytest.approx(_xla_cost(c)["flops"], rel=1e-6)
+    assert r["flops"] == pytest.approx(c.cost_analysis()["flops"], rel=1e-6)
 
 
 def test_nested_scan_multiplies():

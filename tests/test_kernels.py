@@ -46,14 +46,15 @@ def test_bounded_search_shapes_dtypes(n, m, width, dtype):
 
 
 def test_bounded_search_overflow_fallback():
-    """Every query in ONE tile: capacity overflow must stay exact."""
+    """Every query in ONE tile (the old per-tile capacity overflow case):
+    the per-query grid re-uses the resident tile and stays exact."""
     keys = np.arange(10_000, dtype=np.uint64) * 3 + 5
     q = keys[np.random.default_rng(0).integers(0, 100, 5_000)]  # tile 0 only
     lb = np.searchsorted(keys, q).astype(np.int64)
     lo = np.maximum(lb - 10, 0)
     got = lower_bound_windows(jnp.asarray(keys), jnp.asarray(q),
                               jnp.asarray(lo, jnp.int32), max_width=64,
-                              capacity=64, interpret=True)
+                              interpret=True)
     np.testing.assert_array_equal(np.asarray(got), lb)
 
 

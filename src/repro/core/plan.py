@@ -406,7 +406,8 @@ class LookupPlan:
         run = self._lb_run(backend, interpret, mesh)
 
         def merged(q, delta_padded, ops):
-            lb_delta = jnp.searchsorted(delta_padded, q, side="left")
+            with jax.named_scope("merge"):
+                lb_delta = jnp.searchsorted(delta_padded, q, side="left")
             return run(q, ops) + lb_delta.astype(jnp.int64)
 
         return merged
@@ -448,14 +449,15 @@ class LookupPlan:
 
         def scan(q, delta_padded, ops):
             pos_b = run(q, ops)
-            pos_d = jnp.searchsorted(
-                delta_padded, q, side="left").astype(jnp.int64)
-            wb = _window_gather(ops["data"], pos_b, m).astype(
-                delta_padded.dtype)
-            wd = _window_gather(delta_padded, pos_d, m)
-            window = jnp.sort(
-                jnp.concatenate([wb, wd], axis=-1), axis=-1)[:, :m]
-            return pos_b + pos_d, window
+            with jax.named_scope("merge"):
+                pos_d = jnp.searchsorted(
+                    delta_padded, q, side="left").astype(jnp.int64)
+                wb = _window_gather(ops["data"], pos_b, m).astype(
+                    delta_padded.dtype)
+                wd = _window_gather(delta_padded, pos_d, m)
+                window = jnp.sort(
+                    jnp.concatenate([wb, wd], axis=-1), axis=-1)[:, :m]
+                return pos_b + pos_d, window
 
         return scan
 
@@ -464,15 +466,19 @@ class LookupPlan:
         """``(q, ops) -> (LB, lo, hi)`` sharing ONE predict between the
         search and the stats on the generic jnp path (the fused / pallas
         paths keep their own lookup and pay a second jnp predict for the
-        stats — still backend-invariant by construction)."""
+        stats — still backend-invariant by construction; it is named
+        ``health_stats/predict``)."""
         predict = self.bounds.predict
         if backend == "jnp":
             fn = search.SEARCH_FNS[self.last_mile]
             max_err = self.statics.max_err
 
             def base_jnp(q, ops):
-                lo, hi = predict(ops["state"], q)
-                pos = fn(ops["data"], q, lo, hi, max_err).astype(jnp.int64)
+                with jax.named_scope("predict"):
+                    lo, hi = predict(ops["state"], q)
+                with jax.named_scope("last_mile"):
+                    pos = fn(ops["data"], q, lo, hi,
+                             max_err).astype(jnp.int64)
                 return pos, lo, hi
 
             return base_jnp
@@ -480,7 +486,9 @@ class LookupPlan:
         run = self._lb_run(backend, interpret, mesh)
 
         def base_other(q, ops):
-            lo, hi = predict(ops["state"], q)
+            with jax.named_scope("health_stats"), \
+                    jax.named_scope("predict"):
+                lo, hi = predict(ops["state"], q)
             return run(q, ops), lo, hi
 
         return base_other
@@ -504,9 +512,11 @@ class LookupPlan:
 
             def run_point_instr(q, n_valid, ops):
                 pos = run(q, ops)
-                stats = health_stats_expr(
-                    pos, None, None, n, max_err, n_valid, point_only=True)
-                return pos, pack_health_stats(stats)
+                with jax.named_scope("health_stats"):
+                    stats = health_stats_expr(
+                        pos, None, None, n, max_err, n_valid,
+                        point_only=True)
+                    return pos, pack_health_stats(stats)
 
             return run_point_instr
 
@@ -514,8 +524,9 @@ class LookupPlan:
 
         def run_instr(q, n_valid, ops):
             pos, lo, hi = base(q, ops)
-            stats = health_stats_expr(pos, lo, hi, n, max_err, n_valid)
-            return pos, pack_health_stats(stats)
+            with jax.named_scope("health_stats"):
+                stats = health_stats_expr(pos, lo, hi, n, max_err, n_valid)
+                return pos, pack_health_stats(stats)
 
         return run_instr
 
@@ -533,10 +544,14 @@ class LookupPlan:
 
         def merged_instr(q, n_valid, delta_padded, ops):
             lb_base, lo, hi = base(q, ops)
-            lb_delta = jnp.searchsorted(delta_padded, q, side="left")
-            stats = health_stats_expr(lb_base, lo, hi, n, max_err, n_valid)
-            return (lb_base + lb_delta.astype(jnp.int64),
-                    pack_health_stats(stats))
+            with jax.named_scope("merge"):
+                lb_delta = jnp.searchsorted(delta_padded, q, side="left")
+            with jax.named_scope("health_stats"):
+                stats = health_stats_expr(lb_base, lo, hi, n, max_err,
+                                          n_valid)
+            lb = lb_base + lb_delta.astype(jnp.int64)
+            with jax.named_scope("health_stats"):
+                return lb, pack_health_stats(stats)
 
         return merged_instr
 
@@ -681,11 +696,14 @@ def _lb_expr(st: _Statics, backend: str, fused: bool,
     ``fused`` selects the registered whole-plan kernel over the generic
     bounds -> `lower_bound_windows` path (pallas only; parity tests run
     both).  ``interpret=None`` runs Mosaic kernels on TPU and the Pallas
-    interpreter elsewhere."""
+    interpreter elsewhere.  The stages are named for the profiler
+    (``predict``, ``last_mile``): HLO metadata only, the compiled
+    instructions are the same."""
     predict = st.predict
     if st.point_only:
         def run_point(q, ops):
-            found, pos = predict(ops["state"], q)
+            with jax.named_scope("predict"):
+                found, pos = predict(ops["state"], q)
             return jnp.where(found, pos, -1).astype(jnp.int64)
 
         return run_point
@@ -695,30 +713,36 @@ def _lb_expr(st: _Statics, backend: str, fused: bool,
             lookup = st.fused.lookup
 
             def run_fused(q, ops):
-                return lookup(ops["fused"], ops["data"], q,
-                              interpret=interpret,
-                              planes=ops.get("planes")).astype(jnp.int64)
+                with jax.named_scope("last_mile"):
+                    return lookup(ops["fused"], ops["data"], q,
+                                  interpret=interpret,
+                                  planes=ops.get("planes")
+                                  ).astype(jnp.int64)
 
             return run_fused
 
         from repro.kernels.bounded_search.ops import lower_bound_windows
 
         def run_pallas(q, ops):
-            lo, _hi = predict(ops["state"], q)
+            with jax.named_scope("predict"):
+                lo, _hi = predict(ops["state"], q)
             # window precondition lo <= LB < lo + max_err holds by the
             # bounds contract (LB <= hi <= lo + max_err - 1)
-            return lower_bound_windows(
-                ops["data"], q, lo, max_width=st.max_err,
-                interpret=interpret,
-                planes=ops.get("planes")).astype(jnp.int64)
+            with jax.named_scope("last_mile"):
+                return lower_bound_windows(
+                    ops["data"], q, lo, max_width=st.max_err,
+                    interpret=interpret,
+                    planes=ops.get("planes")).astype(jnp.int64)
 
         return run_pallas
 
     fn = search.SEARCH_FNS[st.last_mile]
 
     def run_jnp(q, ops):
-        lo, hi = predict(ops["state"], q)
-        return fn(ops["data"], q, lo, hi, st.max_err).astype(jnp.int64)
+        with jax.named_scope("predict"):
+            lo, hi = predict(ops["state"], q)
+        with jax.named_scope("last_mile"):
+            return fn(ops["data"], q, lo, hi, st.max_err).astype(jnp.int64)
 
     return run_jnp
 

@@ -9,16 +9,27 @@ the serve path holds ``None`` and skips even that (`maybe_span`).
 
 Span taxonomy (the ``cat`` field):
 
-  admission   instants at `MicroBatcher.submit` (one per request id) and
-              backlog/rate rejections
+  admission   `admission_rejected` instants (backlog/rate rejections)
   request     one complete span per finished request: admission ->
               futures resolved, args carry rid / kind / n_keys and the
               queue vs execute decomposition
-  serve       dispatch-side phases: launch, device wait ("finalize"),
-              pad+place
+  serve       the async executor's per-batch phases, every one carrying
+              the batch's ``batch`` sequence number: on the dispatch
+              thread ``pin``, ``gather``, ``launch`` (children
+              ``pad_place``, ``enqueue``) and ``ring_wait``; on the
+              completion thread ``finalize`` (children ``device_wait``,
+              ``copy_back``, ``stats_copy``) and ``resolve``.  The sync
+              path records ``pad_place`` and ``device``.
   compile     executable-cache builds (misses and warm-up compiles) —
               the p99 outliers the async executor exists to hide
   lifecycle   index_build/publish (hot-swap), warmup, compaction
+
+Every span opened with `span` (so every `maybe_span` site) also writes a
+`jax.profiler.TraceAnnotation` named ``lookup.<name>`` carrying the args
+known when it opens: under an active profiler the host phases land in
+the same trace as the device operations, on the profiler's clock.  The
+per-request ``request`` span crosses threads and is recorded after the
+fact, so it stays in the ring only.
 
 Export is the Chrome trace-event JSON format ("traceEvents" with "X"
 complete events, µs timestamps), openable in `chrome://tracing` or
@@ -38,7 +49,12 @@ import threading
 import time
 from typing import Dict, List, Optional
 
-__all__ = ["Span", "SpanRecorder", "maybe_span"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["ANNOTATION_PREFIX", "Span", "SpanRecorder", "maybe_span"]
+
+#: Prefix of the profiler annotation each `SpanRecorder.span` writes.
+ANNOTATION_PREFIX = "lookup."
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,7 +73,9 @@ class Span:
 def maybe_span(recorder: Optional["SpanRecorder"], name: str,
                cat: str = "serve", **args):
     """Context manager recording a span when tracing is on, a no-op
-    otherwise — the one guard every instrumentation site uses."""
+    otherwise — the one guard every instrumentation site uses.  It
+    yields the span's args dict (``None`` when off), so a site can add
+    args only known at its end."""
     if recorder is None:
         return contextlib.nullcontext()
     return recorder.span(name, cat=cat, **args)
@@ -102,9 +120,14 @@ class SpanRecorder:
 
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "serve", **args):
+        """Record the enclosed block as a span, and mark it for the
+        profiler as ``lookup.<name>`` with the args given here.  Yields
+        the args dict: entries the block adds reach the ring's span
+        only, since the annotation took its args when it opened."""
         t0 = time.perf_counter()
         try:
-            yield
+            with TraceAnnotation(ANNOTATION_PREFIX + name, **args):
+                yield args
         finally:
             self.add(name, t0, time.perf_counter(), cat=cat, **args)
 

@@ -137,8 +137,8 @@ class MicroBatcher:
         self.class_deadlines = class_deadlines
         self.max_client_keys = max_client_keys
         self.client_rate = client_rate
-        #: optional `repro.obs.trace.SpanRecorder`: admission instants
-        #: (one per rid — the trace's request-id origin) and rejections
+        #: optional `repro.obs.trace.SpanRecorder`: an instant per
+        #: rejected request (an accepted one is the `request` span)
         self.recorder = recorder
         self._counter = counter if counter is not None else MonotonicCounter()
         #: Optional routing hook ``keys -> (topology, shard ids)`` run at
@@ -230,11 +230,6 @@ class MicroBatcher:
                                       rid=rid, kind=kind,
                                       n_keys=int(keys.size))
             raise
-        if self.recorder is not None:
-            # outside the condition lock: tracing must not stretch the
-            # admission critical section every submitter contends on
-            self.recorder.instant("admit", cat="admission", t=req.t_submit,
-                                  rid=rid, kind=kind, n_keys=int(keys.size))
         return rid, fut
 
     def pending_keys_of(self, client) -> int:

@@ -113,6 +113,8 @@ class _Slot:
     version: int = -1            # generation the stats (if any) belong to
     instrumented: bool = False   # out is (payload, packed health stats)
     routed: bool = False         # out is a dispatch._RoutedHandle
+    batch: int = 0               # the executor's sequence number of the
+    #                              taken batch: every span of it carries it
 
 
 _STOP = object()
@@ -311,6 +313,7 @@ class AsyncExecutor:
         self._launch_mu = threading.Lock()   # serializes take+launch order
         self._inflight = 0
         self._inflight_cv = threading.Condition()
+        self._batch_seq = 0                  # taken batches, under _launch_mu
         self._stop = threading.Event()
         self._dispatch_t: Optional[threading.Thread] = None
         self._complete_t: Optional[threading.Thread] = None
@@ -378,11 +381,14 @@ class AsyncExecutor:
         """Walk the service's work items lazily and in order: an insert
         item is APPLIED when reached, so the next run's pinned context
         observes it (the admission-order invariant), while device items
-        launch without blocking."""
-        for item in self.svc._async_work_items(batch):
-            self._launch_item(item)
+        launch without blocking.  The batch gets the next sequence
+        number, the ``batch`` arg of every span its work records."""
+        self._batch_seq += 1
+        seq = self._batch_seq
+        for item in self.svc._async_work_items(batch, seq):
+            self._launch_item(item, seq)
 
-    def _launch_item(self, item: WorkItem) -> None:
+    def _launch_item(self, item: WorkItem, seq: int) -> None:
         svc = self.svc
         group = item.group
         t_oldest = group[0].t_submit
@@ -393,54 +399,62 @@ class AsyncExecutor:
             except BaseException as e:   # noqa: BLE001 — fail the run only
                 self._put(_Slot(group=group, kind=item.kind, error=e,
                                 t_submit_oldest=t_oldest, t_launch=t0,
-                                is_insert=True))
+                                is_insert=True, batch=seq))
                 return
             self._put(_Slot(group=group, kind=item.kind, host=host,
                             m=sum(r.keys.size for r in group),
                             t_submit_oldest=t_oldest, t_launch=t0,
-                            is_insert=True))
+                            is_insert=True, batch=seq))
             return
 
-        keys = (group[0].keys if len(group) == 1
-                else np.concatenate([r.keys for r in group]))
+        rec = svc.recorder
+        with maybe_span(rec, "gather", batch=seq, n_requests=len(group)):
+            keys = (group[0].keys if len(group) == 1
+                    else np.concatenate([r.keys for r in group]))
         t0 = time.perf_counter()
         routed = isinstance(item.ctx, RoutedContext)
+        instr = False
         try:
-            ctx = item.ctx
-            if routed:
-                routes = svc.dispatcher.routes_for(group, ctx.topology)
-                out = svc.dispatcher.launch(
-                    ctx, item.kind, item.aux, keys, routes=routes,
-                    exec_cache=svc.exec_cache)   # launches, never blocks
-                padded = out.padded
-            else:
-                make_fn = ((lambda: ctx.read_fn) if item.kind == "read"
-                           else (lambda: ctx.scan_fn(item.aux)))
-                q, padded = svc.dispatcher.pad_and_place(keys)
-                exe = svc.exec_cache.get(ctx, item.kind, item.aux, padded,
-                                         make_fn, svc.dispatcher)
-                instr = ctx.instrumented and item.kind == "read"
-                args = (np.int32(keys.size),) if instr else ()
-                out = exe(q, *args, *ctx.bind)   # async dispatch: no block
-        except BaseException as e:       # noqa: BLE001 — fail the group only
-            self._put(_Slot(group=group, kind=item.kind, error=e,
-                            t_submit_oldest=t_oldest, t_launch=t0))
-            return
-        rec = svc.recorder
-        if rec is not None:
             # one span per launched slot, carrying the (contiguous,
             # admission-ordered) rid range it holds — the link between
             # request spans and the device work that served them
-            rec.add("launch", t0, time.perf_counter(), cat="serve",
-                    kind=item.kind, padded=int(padded),
-                    n_keys=int(keys.size), n_requests=len(group),
-                    rid_first=group[0].rid, rid_last=group[-1].rid)
+            with maybe_span(rec, "launch", batch=seq, kind=item.kind,
+                            n_keys=int(keys.size), n_requests=len(group),
+                            rid_first=group[0].rid,
+                            rid_last=group[-1].rid) as span_args:
+                ctx = item.ctx
+                if routed:
+                    routes = svc.dispatcher.routes_for(group, ctx.topology)
+                    with maybe_span(rec, "enqueue", batch=seq):
+                        out = svc.dispatcher.launch(
+                            ctx, item.kind, item.aux, keys, routes=routes,
+                            exec_cache=svc.exec_cache)   # never blocks
+                    padded = out.padded
+                else:
+                    make_fn = ((lambda: ctx.read_fn) if item.kind == "read"
+                               else (lambda: ctx.scan_fn(item.aux)))
+                    with maybe_span(rec, "pad_place", batch=seq):
+                        q, padded = svc.dispatcher.pad_and_place(keys)
+                    with maybe_span(rec, "enqueue", batch=seq,
+                                    padded=int(padded)):
+                        exe = svc.exec_cache.get(ctx, item.kind, item.aux,
+                                                 padded, make_fn,
+                                                 svc.dispatcher)
+                        instr = ctx.instrumented and item.kind == "read"
+                        args = (np.int32(keys.size),) if instr else ()
+                        out = exe(q, *args, *ctx.bind)   # async: no block
+                if span_args is not None:
+                    span_args["padded"] = int(padded)
+        except BaseException as e:       # noqa: BLE001 — fail the group only
+            self._put(_Slot(group=group, kind=item.kind, error=e,
+                            t_submit_oldest=t_oldest, t_launch=t0,
+                            batch=seq))
+            return
         self._put(_Slot(group=group, kind=item.kind, out=out, m=keys.size,
                         padded=padded, t_submit_oldest=t_oldest,
                         t_launch=t0,
                         version=ctx.version if routed else ctx.key[0],
-                        instrumented=False if routed else instr,
-                        routed=routed))
+                        instrumented=instr, routed=routed, batch=seq))
 
     def _put(self, slot: _Slot) -> None:
         with self._inflight_cv:
@@ -449,7 +463,12 @@ class AsyncExecutor:
         if self.svc.metrics is not None:
             self.svc.metrics.note_slot_depth(depth)
         if self.running:
-            self._ring.put(slot)   # blocks when the ring is full: bounded
+            try:
+                self._ring.put_nowait(slot)
+            except queue.Full:     # the ring is full: wait, bounded
+                with maybe_span(self.svc.recorder, "ring_wait",
+                                batch=slot.batch):
+                    self._ring.put(slot)
             return
         # inline mode has no completion thread to make room — keep the
         # bounded-ring invariant by completing the oldest slot here
@@ -470,61 +489,86 @@ class AsyncExecutor:
             elif slot.is_insert:
                 svc._complete_insert_slot(slot)
             else:
-                t_wait = time.perf_counter()
+                rec = svc.recorder
+                route_stats = None
                 try:
-                    if slot.routed:
-                        out, route_stats, _ = slot.out.finalize()
-                    else:
-                        out = svc.dispatcher.finalize(
-                            slot.out, slot.m,
-                            instrumented=slot.instrumented)
+                    with maybe_span(rec, "finalize", batch=slot.batch,
+                                    kind=slot.kind, n_keys=slot.m,
+                                    rid_first=slot.group[0].rid,
+                                    rid_last=slot.group[-1].rid):
+                        if slot.routed:
+                            out, route_stats, _ = slot.out.finalize()
+                        else:
+                            out = self._to_host(slot, rec)
                 except BaseException as e:   # noqa: BLE001 — device failure
                     for r in slot.group:     # fails the slot, not the loop
                         r.future._set_exception(e)
                     return
                 t_end = time.perf_counter()
-                if slot.routed:
-                    # per-shard stats land in each SHARD generation's
-                    # health record; route skew feeds the metrics
-                    for ver, stats in route_stats:
-                        svc._note_health(ver, stats, t_end)
-                    if svc.metrics is not None:
-                        svc.metrics.observe_route(slot.out.counts,
-                                                  slot.out.padded)
-                elif slot.instrumented:
-                    # instrumented read: route the device-reduced stats
-                    # to the record of the generation the slot ran on
-                    out, stats = out
-                    svc._note_health(slot.version, stats, t_end)
-                off = 0
-                for r in slot.group:
-                    end = off + r.keys.size
-                    r.future._set_result(
-                        tuple(o[off:end] for o in out)
-                        if isinstance(out, tuple) else out[off:end])
-                    off = end
-                rec = svc.recorder
-                if rec is not None:
-                    rec.add("finalize", t_wait, t_end, cat="serve",
-                            kind=slot.kind, n_keys=slot.m,
-                            rid_first=slot.group[0].rid,
-                            rid_last=slot.group[-1].rid)
-                    for r in slot.group:
-                        rec.request(r.rid, kind=r.kind,
-                                    n_keys=r.keys.size,
-                                    t_submit=r.t_submit,
-                                    t_launch=slot.t_launch, t_end=t_end)
-                svc.metrics.observe_batch(
-                    n_keys=slot.m, padded=slot.padded,
-                    n_requests=len(slot.group),
-                    t_oldest_submit=slot.t_submit_oldest,
-                    t_start=slot.t_launch, t_end=t_end,
-                    per_request=[(r.t_submit, r.keys.size, r.priority)
-                                 for r in slot.group])
+                with maybe_span(rec, "resolve", batch=slot.batch,
+                                n_requests=len(slot.group)):
+                    self._resolve(slot, out, route_stats, t_end)
         finally:
             with self._inflight_cv:
                 self._inflight -= 1
                 self._inflight_cv.notify_all()
+
+    def _to_host(self, slot: _Slot, rec):
+        """Block on a launched slot's device results and bring them to
+        the host (`ShardedDispatcher.finalize`); an instrumented slot's
+        packed stats vector crosses in one transfer of its own.  With a
+        recorder the wait is split into ``device_wait``, ``copy_back``
+        and ``stats_copy`` spans."""
+        import jax
+
+        payload, stats = slot.out if slot.instrumented else (slot.out, None)
+        if rec is not None:
+            with rec.span("device_wait", batch=slot.batch):
+                jax.block_until_ready(slot.out)
+        with maybe_span(rec, "copy_back", batch=slot.batch):
+            out = self.svc.dispatcher.finalize(payload, slot.m)
+        if stats is None:
+            return out
+        with maybe_span(rec, "stats_copy", batch=slot.batch):
+            return out, np.asarray(stats)
+
+    def _resolve(self, slot: _Slot, out, route_stats, t_end: float) -> None:
+        """Everything after a slot's device results reached the host:
+        health stats, per-request slicing, futures, request spans and
+        the batch metrics."""
+        svc = self.svc
+        if slot.routed:
+            # per-shard stats land in each SHARD generation's health
+            # record; route skew feeds the metrics
+            for ver, stats in route_stats:
+                svc._note_health(ver, stats, t_end)
+            if svc.metrics is not None:
+                svc.metrics.observe_route(slot.out.counts, slot.out.padded)
+        elif slot.instrumented:
+            # instrumented read: route the device-reduced stats to the
+            # record of the generation the slot ran on
+            out, stats = out
+            svc._note_health(slot.version, stats, t_end)
+        off = 0
+        for r in slot.group:
+            end = off + r.keys.size
+            r.future._set_result(
+                tuple(o[off:end] for o in out)
+                if isinstance(out, tuple) else out[off:end])
+            off = end
+        rec = svc.recorder
+        if rec is not None:
+            for r in slot.group:
+                rec.request(r.rid, kind=r.kind, n_keys=r.keys.size,
+                            t_submit=r.t_submit, t_launch=slot.t_launch,
+                            t_end=t_end)
+        svc.metrics.observe_batch(
+            n_keys=slot.m, padded=slot.padded,
+            n_requests=len(slot.group),
+            t_oldest_submit=slot.t_submit_oldest,
+            t_start=slot.t_launch, t_end=t_end,
+            per_request=[(r.t_submit, r.keys.size, r.priority)
+                         for r in slot.group])
 
     # -- synchronous faces ------------------------------------------------
     def _drain_launches(self) -> int:

@@ -213,7 +213,7 @@ class MutableLookupService(LookupService):
             sample_key=int(np.asarray(view.generation.data[:1])[0]),
             instrumented=self.health is not None)
 
-    def _async_work_items(self, batch):
+    def _async_work_items(self, batch, seq: int):
         """Re-pin PER RUN (the sync `_process_batch` contract): an
         insert item is applied when the executor reaches it, and the
         generator resumes with a fresh view for the next run."""
@@ -224,7 +224,7 @@ class MutableLookupService(LookupService):
                                apply_fn=self._insert_apply)
             else:
                 yield from self._async_items_for_run(
-                    kind, run, self._async_context())
+                    kind, run, self._pinned_context(seq))
 
     def _complete_insert_slot(self, slot) -> None:
         """Resolve a host-ready insert slot in ring order — results were

@@ -579,12 +579,19 @@ class LookupService:
             sample_key=int(np.asarray(gen.data[:1])[0]),
             instrumented=self.health is not None)
 
-    def _async_work_items(self, batch):
+    def _pinned_context(self, seq: int) -> AsyncContext:
+        """`_async_context` for batch ``seq`` of the executor, recorded
+        as its ``pin`` span."""
+        with maybe_span(self.recorder, "pin", batch=seq):
+            return self._async_context()
+
+    def _async_work_items(self, batch, seq: int):
         """Lazily yield `WorkItem`s for one taken batch, in admission
         order — the async twin of `_process_batch`, with the context
         pinned ONCE for the whole batch (the mutable subclass re-pins
-        per run and interleaves insert application)."""
-        ctx = self._async_context()
+        per run and interleaves insert application).  ``seq`` is the
+        executor's sequence number of the batch."""
+        ctx = self._pinned_context(seq)
         for run in self._runs(batch, key=lambda r: r.kind):
             yield from self._async_items_for_run(run[0].kind, run, ctx)
 

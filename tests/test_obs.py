@@ -423,10 +423,8 @@ def test_traced_service_reconciles_p99_and_ids(executor):
     trace = json.loads(json.dumps(svc.recorder.to_chrome()))
     lat = SpanRecorder.request_latencies_s(trace)
     assert len(lat) == len(futs)            # one span per request, by rid
-    # admission instants carry the same rids the request spans close out
-    admits = {e["args"]["rid"] for e in trace["traceEvents"]
-              if e.get("cat") == "admission" and e["ph"] == "i"}
-    assert admits == set(lat)
+    # the request spans close out exactly the rids admission handed out
+    assert set(lat) == {f.rid for f in futs}
     snap = svc.metrics.snapshot()
     trace_p99 = float(np.quantile(np.asarray(sorted(lat.values())), 0.99,
                                   method="higher"))
@@ -441,9 +439,11 @@ def test_traced_service_reconciles_p99_and_ids(executor):
     # first-touch lowering of the instrumented executable in-band, §15)
     # burns nothing
     assert w["slo_violations"] == 0
-    # serve-side spans exist for the executor that ran
+    # serve-side spans exist for the executor that ran; an accepted
+    # request records no admission instant (its request span starts at
+    # the same t_submit)
     cats = {e.get("cat") for e in trace["traceEvents"] if e["ph"] != "M"}
-    assert "serve" in cats and "admission" in cats
+    assert "serve" in cats and "admission" not in cats
 
 
 def test_traced_mutable_service_records_insert_and_compaction_spans():
@@ -473,3 +473,133 @@ def test_traced_mutable_service_records_insert_and_compaction_spans():
     assert by_name["compaction"][0].cat == "lifecycle"
     assert "index_build" in by_name         # the compaction's rebuild
     assert "publish" in by_name             # ...and its hot-swap
+
+
+# ---------------------------------------------------------------------------
+# profiler annotations: the recorder's spans in the profiler's own trace
+# ---------------------------------------------------------------------------
+def _serve_under_profiler(tmp_path, trace):
+    import jax
+
+    from perfbench import scopetrace
+    from repro.data import sosd
+    from repro.serve.lookup import LookupService, LookupServiceConfig
+
+    keys = sosd.generate("amzn", 20_000, seed=3)
+    q = sosd.make_queries(keys, 1_024, seed=5)
+    svc = LookupService(keys, LookupServiceConfig(
+        index="pgm", hyper=dict(eps=32), max_batch=256, deadline_ms=1.0,
+        executor="async", trace=trace))
+    with svc:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            futs = [svc.submit(q[i:i + 128]) for i in range(0, len(q), 128)]
+            for f in futs:
+                f.result(timeout=60.0)
+        finally:
+            jax.profiler.stop_trace()
+    return svc, scopetrace.read(str(tmp_path))
+
+
+def test_profiler_trace_holds_lookup_annotations(tmp_path):
+    """Under an active profiler, the traced service's dispatch and
+    completion spans appear as `lookup.*` host events carrying the same
+    `batch` numbers as the recorder's spans."""
+    svc, raw = _serve_under_profiler(tmp_path, trace=True)
+    ann = {}
+    for name, _, dur, args in raw["lookup"]:
+        assert dur >= 0
+        ann.setdefault(name, set()).add(int(args["batch"]))
+    ring = {}
+    for s in svc.recorder.spans():
+        if s.name in ("pin", "launch", "finalize"):
+            ring.setdefault("lookup." + s.name, set()).add(s.args["batch"])
+    for name in ("lookup.pin", "lookup.launch", "lookup.finalize"):
+        assert ann.get(name), name
+        assert ann[name] <= ring[name]
+    # the request span is recorded after the fact: ring only
+    assert "lookup.request" not in ann
+
+
+def test_untraced_service_writes_no_annotation(tmp_path):
+    svc, raw = _serve_under_profiler(tmp_path, trace=False)
+    assert svc.recorder is None
+    assert raw["lookup"] == []
+
+
+def test_profiler_trace_names_the_served_plan_stages(tmp_path):
+    """The trace's own copy of the served program's HLO gives each of
+    its instructions a scope path that names the plan stage."""
+    import glob
+
+    from perfbench import scopetrace
+
+    _serve_under_profiler(tmp_path, trace=False)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    with open(path, "rb") as f:
+        names = scopetrace.op_names(f.read())
+    scopes = {scope for module, table in names.items()
+              if module.startswith("jit_run_instr(")
+              for scope in table.values()}
+    for stage in ("predict", "last_mile", "health_stats"):
+        assert any(scopetrace.under(s, stage) for s in scopes), stage
+
+
+# ---------------------------------------------------------------------------
+# named plan stages: HLO metadata only
+# ---------------------------------------------------------------------------
+def _compiled_text(fn, *args):
+    """Compiled HLO without metadata or debug sections."""
+    import re
+
+    text = fn.lower(*args).compile().as_text().split("\nFileNames")[0]
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    return re.sub(r"stack_frame_id=\d+", "", text)
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+@pytest.mark.parametrize("index,hyper", [
+    ("pgm", dict(eps=32)), ("radix_spline", dict(eps=32, radix_bits=12))])
+def test_instrumented_program_names_its_stages(index, hyper, backend,
+                                               monkeypatch):
+    """The lowered instrumented program carries the `predict`,
+    `last_mile` and `health_stats` scopes; without them it compiles to
+    the same instructions and gives bit-identical answers."""
+    import contextlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import plan as plan_mod
+    from repro.core import spec as spec_mod
+    from repro.data import sosd
+
+    keys = sosd.generate("amzn", 20_000, seed=3)
+    q = jnp.asarray(sosd.make_queries(keys, 512, seed=5))
+    build = spec_mod.build(spec_mod.IndexSpec(
+        index, hyper, backend=backend).validated(), keys)
+    plan = plan_mod.lower(build, jnp.asarray(keys))
+    ops = plan.operands(backend)
+    args = (q, np.int32(q.size), ops)
+
+    def program():
+        return jax.jit(plan._program_expr("instr", backend, None, False,
+                                          None, None))
+
+    scoped = program()
+    text = scoped.lower(*args).as_text(debug_info=True)
+    for stage in ("predict", "last_mile", "health_stats"):
+        assert f"/{stage}/" in text, stage
+    pos, stats = scoped(*args)
+    np.testing.assert_array_equal(
+        np.asarray(pos), np.searchsorted(keys, np.asarray(q), side="left"))
+
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = program()
+    assert "/predict/" not in plain.lower(*args).as_text(debug_info=True)
+    assert _compiled_text(plain, *args) == _compiled_text(scoped, *args)
+    pos0, stats0 = plain(*args)
+    np.testing.assert_array_equal(np.asarray(pos0), np.asarray(pos))
+    np.testing.assert_array_equal(np.asarray(stats0), np.asarray(stats))

@@ -518,3 +518,58 @@ def test_insert_failure_fails_only_that_run(cell):
     merged = np.union1d(half, fresh)
     np.testing.assert_array_equal(
         svc.lookup(q[:64]), base.lower_bound_oracle(merged, q[:64]))
+
+
+# ---------------------------------------------------------------------------
+# tracing: one span of each phase per device batch, joined by `batch`
+# ---------------------------------------------------------------------------
+_PHASES = ("pin", "gather", "launch", "finalize", "resolve")
+_CHILDREN = {"pad_place": "launch", "enqueue": "launch",
+             "device_wait": "finalize", "copy_back": "finalize",
+             "stats_copy": "finalize"}
+
+
+@pytest.mark.parametrize("mutable", [False, True],
+                         ids=["LookupService", "MutableLookupService"])
+def test_traced_batches_have_one_span_per_phase(cell, mutable):
+    """Every launched device batch records exactly one span of each
+    dispatch and completion phase, all carrying the batch's sequence
+    number; each child span lies inside its parent on the same thread."""
+    keys, q, lb = cell
+    kw = dict(index="pgm", hyper=dict(eps=32), max_batch=128,
+              deadline_ms=1.0, executor="async", trace=True, health=True)
+    svc = (MutableLookupService(keys, MutableLookupServiceConfig(
+        auto_compact=False, **kw)) if mutable
+        else LookupService(keys, LookupServiceConfig(**kw)))
+    with svc:
+        futs = [svc.submit(q[i * 64:(i + 1) * 64]) for i in range(12)]
+        got = np.concatenate([f.result(60.0) for f in futs])
+    np.testing.assert_array_equal(got, lb[:12 * 64])
+
+    spans = [s for s in svc.recorder.spans()
+             if s.name in _PHASES or s.name in _CHILDREN]
+    by = {}
+    for s in spans:
+        by.setdefault(s.args["batch"], {}).setdefault(s.name, []).append(s)
+    launched = {s.args["batch"] for s in spans if s.name == "launch"}
+    assert launched and set(by) == launched
+    rids = []
+    for b, named in by.items():
+        for name in _PHASES:
+            assert len(named.get(name, ())) == 1, (b, name)
+        launch, fin = named["launch"][0], named["finalize"][0]
+        assert fin.args["rid_first"] == launch.args["rid_first"]
+        assert fin.args["rid_last"] == launch.args["rid_last"]
+        rids.extend(range(launch.args["rid_first"],
+                          launch.args["rid_last"] + 1))
+        # dispatch phases on one thread, in order; completion on another
+        pin, gather = named["pin"][0], named["gather"][0]
+        assert pin.tid == gather.tid == launch.tid != fin.tid
+        assert pin.t0 + pin.dur <= gather.t0 <= launch.t0
+        assert fin.t0 + fin.dur <= named["resolve"][0].t0
+        for child, parent in _CHILDREN.items():
+            (c,) = named[child]
+            (p,) = named[parent]
+            assert c.tid == p.tid
+            assert p.t0 <= c.t0 and c.t0 + c.dur <= p.t0 + p.dur
+    assert sorted(rids) == sorted(f.rid for f in futs)

@@ -75,7 +75,10 @@ class AsyncContext:
     read_fn: Callable          # (q, *bind) -> positions
     scan_fn: Callable          # m -> ((q, *bind) -> (positions, window))
     bind: Tuple = ()           # device operands appended after q (pytrees)
-    sample_key: int = 1        # a valid key for warm-up dummy batches
+    #: a valid key for warm-up dummy batches: the pinned generation's
+    #: `Generation.sample_key`, fixed when it was made, so a pin reads
+    #: no device memory
+    sample_key: int = 1
     #: Health telemetry (DESIGN.md §15): when set, ``read_fn`` is the
     #: plan's instrumented executable ``(q, n_valid, *bind) -> (pos,
     #: stats)`` — reads pass the real batch size as a dynamic int32

@@ -210,7 +210,7 @@ class MutableLookupService(LookupService):
             read_fn=read,
             scan_fn=scan_for,
             bind=(delta_dev, ops),
-            sample_key=int(np.asarray(view.generation.data[:1])[0]),
+            sample_key=view.generation.sample_key,
             instrumented=self.health is not None)
 
     def _async_work_items(self, batch, seq: int):

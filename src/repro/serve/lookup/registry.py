@@ -49,6 +49,10 @@ class Generation:
     plan: LookupPlan          # the build lowered to the plan IR
     fn: Callable              # plan-compiled lookup: queries -> positions
     n_keys: int
+    #: The first key, read once when the generation is made: the valid
+    #: key warm-up fills its dummy batches with.  A pin reads it from
+    #: here and touches no device memory.
+    sample_key: int
     backend: str = "jnp"      # plan backend this generation serves with
     #: The validated `IndexSpec` this generation was built from — the
     #: serializable address of the serving unit (hot-swap, sharded
@@ -242,7 +246,8 @@ class IndexRegistry:
                         shard: Optional[int] = None) -> Generation:
         """Lower a build to a versioned Generation WITHOUT publishing it
         — the routed publish path assembles several of these and swaps
-        them in as one unit."""
+        them in as one unit.  Reads the first key off the device once,
+        as the generation's `sample_key`."""
         plan = make_plan(build, data, last_mile=last_mile)
         if spec is None:
             spec = build.meta.get("spec")
@@ -257,6 +262,7 @@ class IndexRegistry:
             plan=plan,
             fn=plan.compile(backend=backend),
             n_keys=int(data.shape[0]),
+            sample_key=int(np.asarray(data[:1])[0]),
             backend=backend,
             spec=spec,
             shard=shard,

@@ -448,7 +448,6 @@ class LookupService:
         for s, sgen in enumerate(gen.shards):
             reps = []
             spill = gen.spill(s, spill_len)
-            sample_key = int(np.asarray(sgen.data[:1])[0])
             for r, lane in enumerate(self.dispatcher.lanes[s]):
                 read_fn, scan_fn, ops = self._programs(sgen, lane,
                                                        donate=self._donate)
@@ -459,7 +458,7 @@ class LookupService:
                     read_fn=read_fn,
                     scan_fn=scan_fn,
                     bind=(ops,),
-                    sample_key=sample_key,
+                    sample_key=sgen.sample_key,
                     instrumented=instrumented))
             lane_ctxs.append(tuple(reps))
         rctx = RoutedContext(
@@ -576,7 +575,7 @@ class LookupService:
             read_fn=read_fn,
             scan_fn=scan_fn,
             bind=(ops,),
-            sample_key=int(np.asarray(gen.data[:1])[0]),
+            sample_key=gen.sample_key,
             instrumented=self.health is not None)
 
     def _pinned_context(self, seq: int) -> AsyncContext:

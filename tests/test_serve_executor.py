@@ -573,3 +573,73 @@ def test_traced_batches_have_one_span_per_phase(cell, mutable):
             assert c.tid == p.tid
             assert p.t0 <= c.t0 and c.t0 + c.dur <= p.t0 + p.dur
     assert sorted(rids) == sorted(f.rid for f in futs)
+
+
+# ---------------------------------------------------------------------------
+# pinning: the warm-up sample key is a constant of the generation
+# ---------------------------------------------------------------------------
+def _svc_of(keys, mutable, **over):
+    kw = dict(index="pgm", hyper=dict(eps=32), max_batch=128,
+              deadline_ms=1.0, executor="async")
+    kw.update(over)
+    if mutable:
+        return MutableLookupService(keys, MutableLookupServiceConfig(
+            auto_compact=False, **kw))
+    return LookupService(keys, LookupServiceConfig(**kw))
+
+
+class _UnreadableKeys:
+    """Stands in for a generation's device key array: any read of it
+    fails the test."""
+
+    def __getitem__(self, _):
+        raise AssertionError("the pin read the generation's key array")
+
+    def __array__(self, *args, **kwargs):
+        raise AssertionError("the pin copied the generation's key array")
+
+
+@pytest.mark.parametrize("mutable", [False, True],
+                         ids=["LookupService", "MutableLookupService"])
+def test_pin_reads_no_key_data(cell, mutable):
+    """Once the operands are placed, pinning a batch's context reads
+    nothing of the generation's key array: no device slice, no copy to
+    the host — and serving stays exact without it."""
+    keys, q, lb = cell
+    svc = _svc_of(keys, mutable)
+    with svc:
+        gen = svc.generation
+        real = gen.data
+        object.__setattr__(gen, "data", _UnreadableKeys())
+        try:
+            ctxs = [svc._pinned_context(seq) for seq in range(64)]
+            got = svc.lookup(q[:300], timeout=60.0)
+        finally:
+            object.__setattr__(gen, "data", real)
+    assert {c.key[0] for c in ctxs} == {gen.version}
+    assert {c.sample_key for c in ctxs} == {int(keys[0])}
+    np.testing.assert_array_equal(got, lb[:300])
+
+
+@pytest.mark.parametrize("mutable", [False, True],
+                         ids=["LookupService", "MutableLookupService"])
+def test_sample_key_is_first_key_across_hot_swaps(cell, mutable):
+    """The sample key is the generation's first key, the same on every
+    hot-swap of the same keys, and warm-up compiles one executable per
+    bucket for each generation."""
+    keys, _, _ = cell
+    svc = _svc_of(keys, mutable)
+    with svc:
+        n_warm = svc.exec_cache.warm_compiles
+        assert n_warm == len(svc._resolved_warm_buckets()) > 0
+        versions = set()
+        for swap in range(3):
+            if swap:
+                svc.swap_keys(keys)
+                svc.warm_wait(60.0)
+            gen = svc.generation
+            ctx = svc._pinned_context(swap)
+            assert ctx.key[0] == gen.version not in versions
+            versions.add(gen.version)
+            assert ctx.sample_key == gen.sample_key == int(keys[0])
+        assert svc.exec_cache.warm_compiles == 3 * n_warm

@@ -385,3 +385,15 @@ def test_donated_query_buffer_parity(datasets, queries):
         assert np.array_equal(svc.lookup(q), _oracle(keys, q))  # reuse
     finally:
         svc.stop()
+
+
+def test_routed_shards_sample_key_is_first_shard_key(datasets, routed_svc):
+    """Each shard generation's warm-up sample key is the first key of its
+    own range, and every lane context of the shard carries it."""
+    keys = datasets["amzn"]
+    gen = routed_svc.generation
+    offs = gen.topology.offsets
+    rctx = routed_svc._routed_context(gen)
+    for s, sgen in enumerate(gen.shards):
+        assert sgen.sample_key == int(keys[offs[s]])
+        assert {c.sample_key for c in rctx.lane_ctxs[s]} == {sgen.sample_key}

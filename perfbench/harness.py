@@ -203,22 +203,55 @@ def _warm_buckets(max_batch: int, keys_per_request: int) -> tuple:
     return tuple(out) + (max(max_batch, keys_per_request),)
 
 
-def build_service(cfg: dict, keys: np.ndarray, devices, keys_per_request: int,
-                  trace: bool):
+def service_config(cfg: dict, keys_per_request: int, trace: bool):
+    """The `LookupServiceConfig` a configuration states.  Its
+    ``service`` may give a topology, ``shards`` and ``replicas`` (1 and
+    1 when absent: one key set broadcast over the cell's chips).  A
+    routed batch splits over the shards by key, so a lane may get
+    anything from one key to the whole batch: a routed service is given
+    no warm buckets, and the program warms every lane at every bucket
+    from its ``pad_quantum`` to ``max_batch``."""
     from repro.core.spec import IndexSpec
-    from repro.serve.lookup import LookupService, LookupServiceConfig
-    from repro.serve.lookup.dispatch import data_axis_mesh
+    from repro.serve.lookup import LookupServiceConfig
 
     s = cfg["service"]
-    conf = LookupServiceConfig(
+    shards, replicas = int(s.get("shards", 1)), int(s.get("replicas", 1))
+    max_batch = int(s["max_batch"])
+    warm = () if shards > 1 else _warm_buckets(max_batch, keys_per_request)
+    return LookupServiceConfig(
         spec=IndexSpec(cfg["index"]["name"], dict(cfg["index"]["hyper"]),
                        backend=s["backend"]),
-        executor=s["executor"], max_batch=int(s["max_batch"]),
+        executor=s["executor"], max_batch=max_batch,
         deadline_ms=float(s["deadline_ms"]), health=bool(s["health"]),
-        slots=int(s["slots"]),
-        warm_buckets=_warm_buckets(int(s["max_batch"]), keys_per_request),
-        trace=trace, trace_capacity=TRACE_SPANS)
-    return LookupService(keys, conf, mesh=data_axis_mesh(devices))
+        slots=int(s["slots"]), warm_buckets=warm, trace=trace,
+        trace_capacity=TRACE_SPANS, shards=shards, replicas=replicas)
+
+
+def build_service(cfg: dict, keys: np.ndarray, devices, keys_per_request: int,
+                  trace: bool):
+    from repro.serve.lookup import LookupService
+    from repro.serve.lookup.dispatch import data_axis_mesh
+
+    return LookupService(keys, service_config(cfg, keys_per_request, trace),
+                         mesh=data_axis_mesh(devices))
+
+
+def build_numbers(gen) -> Dict[str, Any]:
+    """The index build's meta numbers that the readers use.  A routed
+    generation has one build a shard: ``max_err`` and ``levels`` are the
+    largest of the shards' (the kernel's window is bounded by the
+    largest error, as `RoutedGeneration.max_err` says), and every other
+    number is shard 0's, whose plan stands for the whole."""
+    from repro.serve.lookup.registry import RoutedGeneration
+
+    metas = ([g.build.meta for g in gen.shards]
+             if isinstance(gen, RoutedGeneration) else [gen.build.meta])
+    out = {key: v for key, v in metas[0].items()
+           if isinstance(v, (int, float))}
+    for key in ("max_err", "levels"):
+        if key in out:
+            out[key] = max(m[key] for m in metas)
+    return out
 
 
 def _reduce_trace(log_dir: str, anchor_pc: float, t_start: float,
@@ -255,6 +288,24 @@ def _check(reference, keys, traffic, window, k: int) -> dict:
             "unanswered": unanswered, "errors": window.errors}
 
 
+def _cover(gaps: list, intervals: list) -> list:
+    """How much of each of the sorted disjoint ``gaps`` the sorted
+    disjoint ``intervals`` cover, in one sweep: an interval that ends
+    before a gap starts ends before every later gap starts too.  Each
+    sum adds the overlaps in the order `tracefile.overlap` does."""
+    out, j = [], 0
+    for a, b in gaps:
+        while j < len(intervals) and intervals[j][1] <= a:
+            j += 1
+        total, k = 0, j
+        while k < len(intervals) and intervals[k][0] < b:
+            x, y = intervals[k]
+            total += max(0.0, min(y, b) - max(x, a))
+            k += 1
+        out.append(total)
+    return out
+
+
 def _idle_by_host(trace: dict, spans: list, ops: list) -> list:
     """Device idle time in the window, by what the host was doing: in a
     `launch` (pad, place, enqueue), `finalize` (wait, copy back,
@@ -273,9 +324,11 @@ def _idle_by_host(trace: dict, spans: list, ops: list) -> list:
     acts["requests queued"] = union(
         [(s.t0, s.t0 + s.args["queue_us"] / 1e6) for s in spans
          if s.name == "request" and s.args])
+    gaps = tracefile.gaps(ops, trace["t0"], trace["t1"])
+    covers = {n: _cover(gaps, iv) for n, iv in acts.items()}
     tot: Dict[str, float] = {}
-    for a, b in tracefile.gaps(ops, trace["t0"], trace["t1"]):
-        cover = {n: tracefile.overlap(iv, a, b) for n, iv in acts.items()}
+    for i, (a, b) in enumerate(gaps):
+        cover = {n: c[i] for n, c in covers.items()}
         best = max(cover, key=cover.get)
         label = best if cover[best] > 0 else "no request pending"
         tot[label] = tot.get(label, 0.0) + (b - a) / 1e9
@@ -299,8 +352,11 @@ class Setup:
 
 
 def set_up(bench: Bench, name: str, seed: int, trace: bool) -> Setup:
-    """Generate the key set, build the service the configuration states
-    and warm every batch bucket the traffic can dispatch."""
+    """Generate the key set, build the service the configuration states,
+    its topology included, and warm every batch bucket the traffic can
+    dispatch.  The readers' build numbers come from `build_numbers`: of
+    a routed generation, ``max_err`` and ``levels`` are the largest of
+    its shards', every other number shard 0's."""
     cell = bench.workload(name)
     devices = require_devices(int(cell["chips"]))
     _enable_compile_cache()
@@ -318,11 +374,9 @@ def set_up(bench: Bench, name: str, seed: int, trace: bool) -> Setup:
     t = time.perf_counter()
     svc.start()
     took["warm"] = time.perf_counter() - t
-    build = {key: v for key, v in svc.generation.build.meta.items()
-             if isinstance(v, (int, float))}
     return Setup(cell, cfg, params, k,
                  bench.module("references", cfg["reference"]), keys, svc,
-                 devices, build, took)
+                 devices, build_numbers(svc.generation), took)
 
 
 def run_cell(bench: Bench, name: str, seed: int, seconds: float,

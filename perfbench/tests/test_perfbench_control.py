@@ -5,7 +5,8 @@ import numpy as np
 import pytest
 
 from perfbench import control
-from perfbench.tests.test_perfbench_harness import _tiny_bench, on_cpu  # noqa: F401
+from perfbench.tests.test_perfbench_harness import ROUTED, _tiny_bench
+from perfbench.tests.test_perfbench_harness import on_cpu  # noqa: F401
 
 
 #: At 200M keys the surrogates' neighbours lie closer than float32 can
@@ -28,7 +29,7 @@ def generate(n, seed):
 
 
 @pytest.mark.parametrize("cell", ["books-pgm.probe1024", "osm-rs.probe1024",
-                                  "books-pgm.get-zipf"])
+                                  "books-pgm.get-zipf", ROUTED])
 def test_float32_control_fails_where_the_program_passes(tmp_path, on_cpu,  # noqa: F811
                                                         cell):
     import json

@@ -369,7 +369,7 @@ class ShadowRetuner:
                     zip(cand_builds, cand_specs, slices)):
                 sg = svc.registry.make_generation(
                     b, gen.shards[s].data, last_mile=sp.last_mile,
-                    backend=sp.backend, spec=sp, shard=s)
+                    backend=sp.backend, spec=sp, shard=s, keys=sl)
                 ok, div = self._verify_fn(sg.fn, sl, self._shard_queries(
                     q, sl))
                 n_div += div
@@ -385,7 +385,7 @@ class ShadowRetuner:
             sp = cand_specs[0]
             cand_gen = svc.registry.make_generation(
                 cand_builds[0], gen.data, last_mile=sp.last_mile,
-                backend=sp.backend, spec=sp)
+                backend=sp.backend, spec=sp, keys=keys)
             ok, n_div = self._verify_fn(cand_gen.fn, keys, q)
             if not ok:
                 return self._reject_verify(cand_specs, n_div, cache_hit,

@@ -79,9 +79,9 @@ FUSED_LOWERERS: Dict[str, "FusedLowering"] = {}
 
 
 def register_fused(name: str, lookup: Callable):
-    """Register ``prepare(plan) -> state`` (the decorated function) with
-    its pure ``lookup(state, data, q, interpret, planes)`` for one index
-    family."""
+    """Register ``prepare(plan, keys) -> state`` (the decorated function)
+    with its pure ``lookup(state, data, q, interpret, planes)`` for one
+    index family."""
     def deco(prepare):
         FUSED_LOWERERS[name] = FusedLowering(prepare=prepare, lookup=lookup)
         return prepare
@@ -239,9 +239,10 @@ def _fn_key(fn):
 @dataclasses.dataclass(frozen=True)
 class FusedLowering:
     """A whole-plan kernel executor registered for one index family:
-    ``prepare(plan)`` builds its device state once per plan, and
-    ``lookup(state, data, q, interpret, planes)`` is the pure expression
-    the programs run."""
+    ``prepare(plan, keys)`` builds its device state once per plan from
+    the host copy of the plan's sorted keys, and ``lookup(state, data, q,
+    interpret, planes)`` is the pure expression the programs run.  The
+    state's ``max_err`` is the window its lookup searches."""
 
     prepare: Callable
     lookup: Callable
@@ -340,6 +341,10 @@ class LookupPlan:
             self._cache["signature"] = sig
         return sig
 
+    def uses_fused(self, backend: str) -> bool:
+        """Whether this backend's lookups run the fused kernel executor."""
+        return self._use_fused(backend, None)
+
     def _use_fused(self, backend: str, fused: Optional[bool]) -> bool:
         if backend not in BACKENDS:
             raise ValueError(f"unknown backend {backend!r}; one of {BACKENDS}")
@@ -360,11 +365,32 @@ class LookupPlan:
 
         if backend != "pallas" or self.point_only:
             return False
+        return kernel_serves(self.served_window(backend, fused))
+
+    def served_window(self, backend: str = "jnp",
+                      fused: Optional[bool] = None) -> int:
+        """The error-bound window the served lookup searches: the fused
+        executor's own (`fused_state`) where one serves, else the
+        bounds stage's ``max_err``."""
         if self._use_fused(backend, fused):
-            width = self.operands(backend, True)["fused"].max_err
-        else:
-            width = self.bounds.max_err
-        return kernel_serves(int(width))
+            return int(self.fused_state().max_err)
+        return int(self.bounds.max_err)
+
+    def fused_state(self, keys: Optional[np.ndarray] = None):
+        """The fused executor's state, prepared once per plan.  ``keys``
+        is the host copy of the sorted keys the build was given; without
+        it they are read back off the device, a copy of the whole key
+        array."""
+        state = self._cache.get("fused")
+        if state is None:
+            if self.fused is None:
+                raise ValueError(
+                    f"plan {self.name!r} has no fused kernel executor")
+            if keys is None:
+                keys = np.asarray(self.data)
+            state = self.fused.prepare(self, keys)
+            self._cache["fused"] = state
+        return state
 
     def operands(self, backend: str = "jnp",
                  fused: Optional[bool] = None) -> Dict[str, Any]:
@@ -379,12 +405,11 @@ class LookupPlan:
             return ops
         ops = {"data": self.data, "state": self.bounds.state}
         if fused:
-            ops["fused"] = self.fused.prepare(self)
+            ops["fused"] = self.fused_state()
         if backend == "pallas" and not self.point_only:
             from repro.kernels.bounded_search import ops as bs
 
-            width = (ops["fused"].max_err if fused else self.bounds.max_err)
-            if bs.kernel_serves(int(width)):
+            if bs.kernel_serves(self.served_window(backend, fused)):
                 ops["planes"] = jax.jit(bs.kernel_planes)(self.data)
         self._cache[key] = ops
         return ops
@@ -697,8 +722,9 @@ def _lb_expr(st: _Statics, backend: str, fused: bool,
     bounds -> `lower_bound_windows` path (pallas only; parity tests run
     both).  ``interpret=None`` runs Mosaic kernels on TPU and the Pallas
     interpreter elsewhere.  The stages are named for the profiler
-    (``predict``, ``last_mile``): HLO metadata only, the compiled
-    instructions are the same."""
+    (``predict``, ``last_mile``; a fused lookup names its own stages the
+    same way): HLO metadata only, the compiled instructions are the
+    same."""
     predict = st.predict
     if st.point_only:
         def run_point(q, ops):
@@ -713,11 +739,9 @@ def _lb_expr(st: _Statics, backend: str, fused: bool,
             lookup = st.fused.lookup
 
             def run_fused(q, ops):
-                with jax.named_scope("last_mile"):
-                    return lookup(ops["fused"], ops["data"], q,
-                                  interpret=interpret,
-                                  planes=ops.get("planes")
-                                  ).astype(jnp.int64)
+                return lookup(ops["fused"], ops["data"], q,
+                              interpret=interpret,
+                              planes=ops.get("planes")).astype(jnp.int64)
 
             return run_fused
 
@@ -788,13 +812,15 @@ def _rmi_fused_lookup(state, data, q, interpret=None, planes=None):
 
 
 @register_fused("rmi", _rmi_fused_lookup)
-def _rmi_fused(plan: LookupPlan):
+def _rmi_fused(plan: LookupPlan, keys: np.ndarray):
     """Whole-plan executor for RMI: the stage-2 fetch kernel + the
     last-mile kernel (`kernels/rmi_lookup`).  The f32 state is refit from
-    the plan's keys with error tables re-verified through the lookup's
-    own arithmetic, so the result is still the exact LB rank —
-    bit-identical to every other backend."""
+    the host keys (stage-1 inference on the plan's device copy) with
+    error tables re-verified through the lookup's own arithmetic, so the
+    result is still the exact LB rank — bit-identical to every other
+    backend.  The lookup searches `rmi_lookup.ops.window_width` keys."""
     from repro.kernels.rmi_lookup import ops as rops
 
     return rops.prepare_f32_state(
-        np.asarray(plan.data), branching=int(plan.meta.get("branching", 1024)))
+        keys, branching=int(plan.meta.get("branching", 1024)),
+        data=plan.data)

@@ -16,7 +16,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import LANES, pad_to
+from repro.kernels.common import LANES, pad_pow2, pad_to
 from repro.kernels.rmi_lookup import ref as _ref
 from repro.kernels.rmi_lookup.kernel import TABLE_BLOCK, rmi_infer_kernel
 from repro.kernels.bounded_search.ops import (QUERY_CHUNK,
@@ -81,9 +81,15 @@ def _kernel_tables(a2f, b2f, err_i):
     return (block(a2f), block(b2f), block(err_i))
 
 
-def prepare_f32_state(keys: np.ndarray, branching: int = 4096) -> F32RMIState:
+def prepare_f32_state(keys: np.ndarray, branching: int = 4096,
+                      data=None) -> F32RMIState:
     """Fit a linear/linear RMI (f64 numpy fit, as repro.core.rmi) and verify
-    its error table under the kernel's f32 inference pipeline."""
+    its error table under the kernel's f32 inference pipeline.
+
+    ``keys`` is the host copy of the sorted keys; ``data``, where the
+    caller already holds the same uint64 keys on the device, is what
+    stage-1 inference runs on instead of a fresh upload (same values, so
+    the state is the same)."""
     keys = np.asarray(keys).astype(np.uint64)
     n = len(keys)
     B = int(branching)
@@ -111,7 +117,9 @@ def prepare_f32_state(keys: np.ndarray, branching: int = 4096) -> F32RMIState:
         scale=scale, branching=B, n=n, max_err=1,
     )
     # bucket assignment + u through the EXACT kernel-side math
-    u_j, bkt_j = _infer_u_bkt(state, jnp.asarray(keys))
+    if data is None or data.dtype != jnp.uint64:
+        data = jnp.asarray(keys)
+    u_j, bkt_j = _infer_u_bkt(state, data)
     u32 = np.asarray(u_j, np.float64)
     bkt = np.asarray(bkt_j).astype(np.int64)
     bkt = np.maximum.accumulate(bkt)  # no-op safeguard (inference monotone)
@@ -196,9 +204,23 @@ def rmi_bounds(state: F32RMIState, queries, interpret: Optional[bool] = None):
     return lo, hi
 
 
+def window_width(state: F32RMIState) -> int:
+    """The static window the lookup searches: ``max_err`` rounded up to
+    the power of two the kernel pads it to anyway.  A wider bound is
+    still a valid one, so answers are unchanged, and states whose bounds
+    share a power of two share one program."""
+    return pad_pow2(int(state.max_err), minimum=128)
+
+
 def rmi_lookup(state: F32RMIState, data, queries,
                interpret: Optional[bool] = None, planes=None):
-    """End-to-end: kernel-fetched RMI bounds -> last-mile search -> exact LB."""
-    lo, _hi = rmi_bounds(state, queries, interpret=interpret)
-    return lower_bound_windows(data, queries, lo, max_width=state.max_err,
-                               interpret=interpret, planes=planes)
+    """End-to-end: kernel-fetched RMI bounds -> last-mile search -> exact LB.
+    The stages are named for the profiler as the plan's are: stage-1
+    inference and the stage-2 fetch ``predict``, the window search
+    ``last_mile``."""
+    with jax.named_scope("predict"):
+        lo, _hi = rmi_bounds(state, queries, interpret=interpret)
+    with jax.named_scope("last_mile"):
+        return lower_bound_windows(data, queries, lo,
+                                   max_width=window_width(state),
+                                   interpret=interpret, planes=planes)

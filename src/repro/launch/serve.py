@@ -105,6 +105,11 @@ def run_lookup(args):
         health=not args.no_health, autotune=at_cfg))
     print(f"serving spec: {svc.generation.spec.to_json()} "
           f"(executor={args.executor})")
+    gen = svc.generation
+    metas = [g.build.meta for g in getattr(gen, "shards", (gen,))]
+    print(f"index: served_window={max(m['served_window'] for m in metas)} "
+          f"kernel_serves={min(m['kernel_serves'] for m in metas)} "
+          f"(0: the exact jnp search serves, not the Pallas kernel)")
     topo = getattr(svc.generation, "topology", None)
     if topo is not None:
         print(f"topology: {topo.describe()}")
@@ -214,7 +219,14 @@ def main():
     ap.add_argument("--max-batch", type=int, default=None)
     ap.add_argument("--dataset", default="amzn",
                     choices=sorted(("amzn", "face", "osm", "wiki")))
-    ap.add_argument("--index", default="rmi")
+    ap.add_argument("--index", default="rmi",
+                    help="index family, served with its defaults on the "
+                         "jnp backend (--spec can name the Pallas one).  "
+                         "The default, an RMI over amzn keys, errs far "
+                         "wider than the Pallas kernel's 2048-key tile, "
+                         "so even on the Pallas backend the exact jnp "
+                         "search would serve it; the `index:` line's "
+                         "kernel_serves says which one serves")
     ap.add_argument("--spec", default=None,
                     help="IndexSpec JSON (overrides --index), e.g. "
                          '\'{"index": "pgm", "hyper": {"eps": 32}}\'')

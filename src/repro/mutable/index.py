@@ -207,7 +207,8 @@ class MutableIndex:
                                             name=self.name,
                                             last_mile=new_spec.last_mile,
                                             backend=new_spec.backend,
-                                            spec=new_spec)
+                                            spec=new_spec,
+                                            keys=snap.base_np)
                 self.spec = new_spec
                 self._view = MutableView(
                     generation=gen, base_np=snap.base_np,
@@ -267,7 +268,7 @@ class MutableIndex:
                 gen = self.registry.publish(build, data, name=self.name,
                                             last_mile=new_spec.last_mile,
                                             backend=new_spec.backend,
-                                            spec=new_spec)
+                                            spec=new_spec, keys=merged_keys)
                 self.spec = new_spec
                 leftover = self._view.delta.minus(snap.delta)
                 self._view = MutableView(

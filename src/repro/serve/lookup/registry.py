@@ -198,13 +198,15 @@ class IndexRegistry:
                 name: str = DEFAULT_NAME,
                 last_mile: Optional[str] = None,
                 backend: str = "jnp",
-                spec: Optional[spec_mod.IndexSpec] = None) -> Generation:
+                spec: Optional[spec_mod.IndexSpec] = None,
+                keys: Optional[np.ndarray] = None) -> Generation:
         """Lower a COMPLETE IndexBuild to its plan, wrap it into a
         generation, and swap it in.  ``spec`` defaults to the spec the
         build carries (`spec.build` stamps it into ``meta``) and is
-        re-aligned to the backend/last-mile the generation serves with."""
+        re-aligned to the backend/last-mile the generation serves with.
+        ``keys`` is the host copy of ``data`` (`make_generation`)."""
         gen = self.make_generation(build, data, last_mile=last_mile,
-                                   backend=backend, spec=spec)
+                                   backend=backend, spec=spec, keys=keys)
         with self._lock:
             self._current[name] = gen
             subscribers = list(self._subscribers)
@@ -243,12 +245,32 @@ class IndexRegistry:
                         last_mile: Optional[str] = None,
                         backend: str = "jnp",
                         spec: Optional[spec_mod.IndexSpec] = None,
-                        shard: Optional[int] = None) -> Generation:
+                        shard: Optional[int] = None,
+                        keys: Optional[np.ndarray] = None) -> Generation:
         """Lower a build to a versioned Generation WITHOUT publishing it
         — the routed publish path assembles several of these and swaps
         them in as one unit.  Reads the first key off the device once,
-        as the generation's `sample_key`."""
+        as the generation's `sample_key`.
+
+        Where the plan serves through a fused kernel executor, its state
+        is prepared here, in a ``fused_prepare`` lifecycle span, from
+        ``keys``: the host copy of ``data`` the build was given (without
+        it the plan reads the key array back off the device).  The
+        build's meta gains ``served_window``, the error-bound window the
+        served lookup searches, and ``kernel_serves``, 1 where the
+        Pallas last-mile kernel serves and 0 where its exact jnp
+        fallback (or the jnp backend) does."""
         plan = make_plan(build, data, last_mile=last_mile)
+        if plan.uses_fused(backend):
+            with maybe_span(self.recorder, "fused_prepare", cat="lifecycle",
+                            n=int(plan.n),
+                            branching=int(plan.meta.get("branching", 0))
+                            ) as args:
+                plan.fused_state(keys)
+                if args is not None:
+                    args["served_window"] = plan.served_window(backend)
+        build.meta["served_window"] = plan.served_window(backend)
+        build.meta["kernel_serves"] = int(plan.kernel_serves(backend))
         if spec is None:
             spec = build.meta.get("spec")
         if spec is not None:
@@ -332,7 +354,7 @@ class IndexRegistry:
                     b, self.place_keys(sl, shard=s, topology=topology),
                     last_mile=shard_specs[s].last_mile,
                     backend=shard_specs[s].backend,
-                    spec=shard_specs[s], shard=s))
+                    spec=shard_specs[s], shard=s, keys=sl))
         return self.publish_routed(gens, topology, name=name, spec=sp,
                                    backend=sp.backend)
 
@@ -358,7 +380,7 @@ class IndexRegistry:
             build = spec_mod.build(sp, keys)
             data = self.place_keys(keys)
         return self.publish(build, data, name=name, last_mile=sp.last_mile,
-                            backend=sp.backend, spec=sp)
+                            backend=sp.backend, spec=sp, keys=keys)
 
     def health_records(self, window_s: float = 10.0) -> list:
         """Per-generation health records (empty when no monitor is

@@ -137,3 +137,44 @@ def test_served_read_program_compiles_at_sosd_scale(pgm_build, one_chip,
     # no key array is embedded: the program text does not grow with n
     small = _lower_read_program(pgm_build, 1_000_000, backend, one_chip)
     assert len(lowered.as_text()) < len(small.as_text()) + 4096
+
+
+def test_fused_rmi_read_program_compiles_at_sosd_scale(one_chip):
+    """The RMI's served read program (health on, fused: root, stage-2
+    fetch kernel, last-mile kernel) at 200M keys and 2^20 leaves, the
+    wiki configuration's shapes: both Mosaic kernels, and the f64
+    health predict beside them."""
+    import dataclasses
+
+    from repro.core import plan as plan_mod
+    from repro.core import spec as spec_mod
+    from repro.data import sosd
+    from repro.kernels.bounded_search.ops import kernel_planes
+    from repro.kernels.rmi_lookup import ops as rops
+
+    branching = 2**20
+    keys = sosd.generate("wiki", 1_000_000, seed=1)
+    build = spec_mod.build(spec_mod.IndexSpec(
+        "rmi", {"branching": branching}), keys)
+    build = dataclasses.replace(build, meta={**build.meta, "n": SOSD_N})
+    fused = dataclasses.replace(
+        rops.prepare_f32_state(keys, branching=branching), n=SOSD_N)
+    assert rops.window_width(fused) <= 2048
+
+    def shapes(tree):
+        return jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+
+    data = _sds((SOSD_N,), jnp.uint64, one_chip)
+    plan = plan_mod.lower(build, data)
+    ops = {"data": data, "state": shapes(build.state),
+           "fused": shapes(fused),
+           "planes": tuple(_sds(p.shape, p.dtype, one_chip)
+                           for p in jax.eval_shape(kernel_planes, data))}
+    prog = plan.program("instr", backend="pallas", interpret=False)
+    compiled = prog.lower(_sds((2048,), jnp.uint64, one_chip),
+                          _sds((), jnp.int32, one_chip), ops).compile()
+    text = compiled.as_text()
+    assert _mosaic_kernels(text) >= 2
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= SOSD_N * 8
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < HBM_BYTES
